@@ -3,7 +3,9 @@
     PYTHONPATH=src python -m benchmarks.run             # all
     PYTHONPATH=src python -m benchmarks.run table1 fig10 ...
 
-Prints one CSV-ish line per row: ``name,us_per_call,derived...``.
+Prints one CSV-ish line per row: ``name,us_per_call,derived...``.  A suite
+that raises prints an ERROR row, the others still run, and the exit code
+is non-zero.
 Heavy steps cache under artifacts/ (CNN training, dry-run compiles), so
 re-runs are fast and the final tee'd output is reproducible.
 """
@@ -36,11 +38,15 @@ SUITES = [
 ]
 
 
-def main() -> None:
+def main() -> int:
     import importlib
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(sys.argv[1:])
     t0 = time.time()
+    failed = []
     for key, modname in SUITES:
         if want and key not in want:
             continue
@@ -50,9 +56,14 @@ def main() -> None:
             rows = mod.run()
         except Exception as e:  # a failed suite must not hide the others
             rows = [{"name": f"{key}/ERROR", "error": f"{type(e).__name__}: {e}"}]
+            failed.append(key)
         _emit(rows)
     print(f"# total {time.time() - t0:.1f}s", flush=True)
+    if failed:
+        print(f"# FAILED suites: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -17,6 +17,7 @@ onto a different MAC array.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -101,19 +102,13 @@ def _packed_forward(p: QuantizedDense | QuantizedDenseGroup,
                     x: jax.Array) -> jax.Array:
     """Forward dispatch for one packed leaf (or fused group's wide call)."""
     pol = p.policy
-    if pol.backend == "pallas" and pol.is_approx and pol.groups == 1:
+    if pol.backend == "pallas" and pol.is_approx:
         from repro.kernels import ops as kops
 
-        if not isinstance(p, QuantizedDenseGroup):
-            return kops.quantized_dense_pallas(x, p).astype(x.dtype)
-        if p.blocked is not None:
-            return kops.quantized_dense_fused_op(
-                x, p.blocked, mode=pol.mode, m=pol.m, use_cv=pol.use_cv)
+        return kops.quantized_dense_pallas(x, p).astype(x.dtype)
     if p.fold is not None:  # serving fast path: folded float GEMMs
         return folded_linear(x, p.fold, pol.mode, pol.m,
                              pol.use_cv).astype(x.dtype)
-    # grouped CV has no Pallas kernel yet: backend="pallas" with
-    # groups > 1 falls back to the jnp grouped path instead of crashing
     return quantized_linear(
         x,
         p.pack,
@@ -188,23 +183,31 @@ def init_dense(key, k: int, n: int, *, bias: bool = True, scale: float | None = 
 # ---------------------------------------------------------------------------
 
 
-def _pack_leaf(p: dict, policy: ApproxPolicy) -> PackedLinear:
-    """Quantize one float linear leaf (2D, or vmapped over a 3D stack)."""
-    import functools
+@functools.partial(jax.jit, static_argnames=("mode", "m", "groups"))
+def _pack_stack(w: jax.Array, b: jax.Array, *, mode, m: int,
+                groups: int) -> PackedLinear:
+    """:func:`pack_linear` over a (layers, k, n) stack, one layer at a
+    time: packing's int32/f32 temporaries stay the size of one layer
+    instead of the whole stack's (at olmo-1b widths a vmapped pack held
+    several GB of them in device memory)."""
+    return jax.lax.map(
+        lambda wb: pack_linear(wb[0], wb[1], mode, m, groups), (w, b))
 
+
+def _pack_leaf(p: dict, policy: ApproxPolicy) -> PackedLinear:
+    """Quantize one float linear leaf (2D, or layer by layer over a 3D
+    stack)."""
     w = p["w"]
     b = p.get("b")
-    fn = functools.partial(
-        pack_linear, mode=policy.mode, m=policy.m, groups=policy.groups
-    )
     if w.ndim == 3:
-        pack = jax.vmap(lambda wi, bi: fn(wi, bi))(
-            w, b if b is not None else jnp.zeros((w.shape[0], w.shape[-1]), w.dtype)
-        )
+        pack = _pack_stack(
+            w, b if b is not None
+            else jnp.zeros((w.shape[0], w.shape[-1]), w.dtype),
+            mode=policy.mode, m=policy.m, groups=policy.groups)
         if b is None:
             pack = dataclasses.replace(pack, bias=None)
         return pack
-    return fn(w, b)
+    return pack_linear(w, b, policy.mode, policy.m, policy.groups)
 
 
 def _act_qp(act_range, w: jax.Array) -> QuantParams:
@@ -220,9 +223,8 @@ def _act_qp(act_range, w: jax.Array) -> QuantParams:
 
 def _maybe_blocked(pack: PackedLinear, a_qp: QuantParams,
                    policy: ApproxPolicy, ndim: int) -> BlockedPack | None:
-    """Offline-blocked serving layout for pallas-backend single-CV packs."""
-    if not (policy.backend == "pallas" and policy.is_approx
-            and policy.groups == 1):
+    """Offline-blocked serving layout for pallas-backend approximate packs."""
+    if not (policy.backend == "pallas" and policy.is_approx):
         return None
     k, n = pack.w_q.shape[-2:]
     bn, bk = serving_blocks(k, n)
@@ -236,7 +238,7 @@ def _maybe_fold(pack: PackedLinear, a_qp: QuantParams,
                 policy: ApproxPolicy) -> dict | None:
     """Folded float serving operands for jnp-path packs (build_fold); the
     pallas-approx path reads the blocked layout instead."""
-    if policy.backend == "pallas" and policy.is_approx and policy.groups == 1:
+    if policy.backend == "pallas" and policy.is_approx:
         return None
     return build_fold(pack, a_qp, policy.mode, policy.m, policy.use_cv)
 

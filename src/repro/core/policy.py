@@ -40,6 +40,10 @@ class ApproxPolicy:
             raise ValueError(f"m={self.m} out of range for 8-bit codes")
         if self.groups < 1:
             raise ValueError("groups must be >= 1")
+        if self.backend == "pallas" and self.groups > 1:
+            raise ValueError(
+                "grouped CV (groups > 1) has no Pallas kernel; use "
+                "backend='jnp'")
 
     @property
     def is_approx(self) -> bool:

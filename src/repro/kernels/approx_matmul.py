@@ -14,18 +14,20 @@ and weight codes W (K, N),
 All integer arithmetic is exact int32; the AM semantics are bit-exact with
 the scalar hardware definitions in :mod:`repro.core.multipliers` (asserted
 against `ref.py` in tests).  The approximate products are *decompositions
-into exact integer matmuls* so the MXU runs at full rate:
+into exact integer matmuls* on int8 operands, the MXU's integer type:
 
     perforated: dot(A & ~mask, W)
     recursive : dot(A, W) - dot(A & mask, W & mask)
-    truncated : dot(A, W) - sum_{i<m} dot(bit_i(A) << i, W mod 2^{m-i})
+    truncated : dot(A, W) - sum_{i<m} 2^i dot(bit_i(A), W mod 2^{m-i})
+
+(uint8 codes that can reach 128 are shifted by -128; see _am_tile_acc).
 
 Grid: (M/bm, N/bn, K/bk) with the K axis innermost ("arbitrary" semantics);
 accumulators live in VMEM scratch across K steps; the epilogue fires on the
 final K step.  Block shapes default to MXU-aligned (128, 128, 512).
 
-TPU is the *target*; CPU validation uses interpret=True (set by ops.py when
-no TPU is present).
+TPU is the *target*; on the CPU backend ops.py runs the kernels in
+interpret mode (the correctness path), and refuses any other backend.
 """
 
 from __future__ import annotations
@@ -40,8 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.multipliers import Mode
 from repro.quant.quantize import (EPI_BIAS, EPI_C, EPI_C0, EPI_ROWS, EPI_SUM_QW,
-                                  EPI_SW, EPI_ZW, META_LEN, META_SA,
-                                  META_TRUE_K, META_ZA)
+                                  EPI_SW, EPI_ZW, META_LEN, META_SA, META_ZA)
 
 # MXU-aligned defaults: int8-friendly tiles, K deep enough to amortize the
 # epilogue; A tile (128x512) + W tile (512x128) + int32 acc (128x128) stay
@@ -51,30 +52,100 @@ DEFAULT_BN = 128
 DEFAULT_BK = 512
 
 
-def _dot_i32(a, b):
-    """Exact int32 matmul of int32-valued tiles (int8-rate on the MXU)."""
+#: uint8 codes 0..255 reach the MXU as int8: an operand that can reach 2^7
+#: is shifted down by this offset, and the shift is folded back exactly.
+_OFFSET = 128
+
+
+def _dot_i8(p, q):
+    """Exact int8 x int8 -> int32 tile matmul (the MXU's integer path)."""
     return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        p.astype(jnp.int8), q.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
 
 
-def _am_tile_acc(a_i32, w_i32, mode: Mode, m: int):
-    """sum_k AM(w, a) for one (bm, bk) x (bk, bn) tile — bit-slice algebra."""
+def _am_terms(mode: Mode, m: int):
+    """sum_k AM(w, a) as signed bit-slice products (static description).
+
+    Each term ``(sign, shift, a_op, w_op)`` contributes
+    ``sign * 2^shift * (a_op(A) @ w_op(W))``; an op is ``(kind, j)``:
+    ``all`` (the codes), ``hi`` (codes with the low j bits cleared),
+    ``lo`` (the low j bits) or ``bit`` (bit j).
+    """
+    full = ("all", 8)
     if mode == "exact" or m == 0:
-        return _dot_i32(a_i32, w_i32)
-    mask = (1 << m) - 1
+        return [(1, 0, full, full)]
     if mode == "perforated":
-        return _dot_i32(a_i32 - (a_i32 & mask), w_i32)
+        return [(1, 0, ("hi", m), full)]
     if mode == "recursive":
-        return _dot_i32(a_i32, w_i32) - _dot_i32(a_i32 & mask, w_i32 & mask)
+        return [(1, 0, full, full), (-1, 0, ("lo", m), ("lo", m))]
     if mode == "truncated":
-        acc = _dot_i32(a_i32, w_i32)
-        for i in range(m):
-            plane_a = ((a_i32 >> i) & 1) << i
-            plane_w = w_i32 & ((1 << (m - i)) - 1)
-            acc = acc - _dot_i32(plane_a, plane_w)
-        return acc
+        return [(1, 0, full, full)] + [
+            (-1, i, ("bit", i), ("lo", m - i)) for i in range(m)]
     raise ValueError(f"unknown mode {mode}")
+
+
+def _op_bits(op) -> int:
+    """Operand values lie in [0, 2^bits)."""
+    kind, j = op
+    return {"all": 8, "hi": 8, "lo": j, "bit": 1}[kind]
+
+
+def _apply_op(x, op):
+    kind, j = op
+    if kind == "all":
+        return x
+    if kind == "hi":
+        return x - (x & ((1 << j) - 1))
+    if kind == "lo":
+        return x & ((1 << j) - 1)
+    return (x >> j) & 1
+
+
+def _w_offset_coef(mode: Mode, m: int) -> int:
+    """Weight of the per-column offset term in the epilogue (see
+    :func:`_am_tile_acc`): the signed count of full-range products."""
+    return sum(sign << shift for sign, shift, a_op, _ in _am_terms(mode, m)
+               if _op_bits(a_op) == 8)
+
+
+def _am_tile_acc(a, w, mode: Mode, m: int):
+    """sum_k AM(w, a) for one (bm, bk) x (bk, bn) tile on int8 operands.
+
+    ``a``/``w`` hold int32 codes 0..255.  Returns ``(acc, row)`` with
+
+        sum_k AM(w, a) == acc + row
+                          + coef * (128 * colsum(w) - 128^2 * bk)
+
+    for ``coef = _w_offset_coef(mode, m)``.  An operand that can reach
+    2^7 is shifted to x - 128 to fit int8: with p = p' + 128 and
+    q = q' + 128, p @ q = p' @ q' + 128 rowsum(p) + 128 colsum(q) - 128^2 bk,
+    and with only q shifted, p @ q = p @ q' + 128 rowsum(p).  A full-range
+    activation operand only ever meets the full-range weight operand ``w``
+    itself, so the colsum terms sum over the K tiles to the pack's sum_qw
+    and the kernel adds them once, in the epilogue.  Planes scaled by 2^i
+    enter the dot as 0/1 bits; the shift is applied to the int32 result.
+    """
+    acc = row = None
+    for sign, shift, a_op, w_op in _am_terms(mode, m):
+        p, q = _apply_op(a, a_op), _apply_op(w, w_op)
+        if _op_bits(w_op) == 8:
+            r = _OFFSET * jnp.sum(p, axis=1, dtype=jnp.int32, keepdims=True)
+            row = _accumulate(row, r, sign, shift)
+            q = q - _OFFSET
+        if _op_bits(a_op) == 8:
+            p = p - _OFFSET
+        acc = _accumulate(acc, _dot_i8(p, q), sign, shift)
+    return acc, row
+
+
+def _accumulate(total, v, sign: int, shift: int):
+    """total + sign * 2^shift * v, with None as the empty total."""
+    v = v << shift if shift else v
+    if total is None:
+        return v if sign > 0 else -v
+    return total + v if sign > 0 else total - v
 
 
 def _x_tile(a_i32, mode: Mode, m: int):
@@ -87,6 +158,49 @@ def _x_tile(a_i32, mode: Mode, m: int):
     raise ValueError(f"unknown mode {mode}")
 
 
+def _accumulate_tile(a, w, acc_ref, row_ref, sumx_ref, sumqa_ref, *,
+                     mode: Mode, m: int, use_cv: bool):
+    """Add one K tile's products and per-row sums into the scratch."""
+    acc, row = _am_tile_acc(a, w, mode, m)
+    acc_ref[...] += acc
+    row_ref[...] += row
+    sumqa_ref[...] += jnp.sum(a, axis=1, dtype=jnp.int32, keepdims=True)
+    if use_cv and mode != "exact" and m > 0:
+        sumx_ref[...] += jnp.sum(
+            _x_tile(a, mode, m), axis=1, dtype=jnp.int32, keepdims=True
+        )
+
+
+def _epilogue(acc_ref, row_ref, sumx_ref, sumqa_ref, *, c, c0, sum_qw, bias,
+              scale, za, zw, k: int, k_total: int, mode: Mode, m: int,
+              use_cv: bool):
+    """Dequantized output, computed as
+    :func:`repro.quant.quantize.quantized_linear` computes it, op for op.
+
+    The code-product sum and every zero-point correction are integers, so
+    the whole bracket  acc - zw*sumqa - za*sum_qw + k*za*zw  is formed in
+    int32 (exact: the true value is bounded by 255^2 * k) and only the CV
+    term and the rescale run in float.  ``za``/``zw``/``sum_qw`` are int32;
+    ``k`` is the true fan-in, ``k_total`` the padded K the tiles covered.
+    """
+    acc = acc_ref[...] + row_ref[...]
+    coef = _w_offset_coef(mode, m)
+    if coef:
+        acc = acc + coef * (_OFFSET * sum_qw - _OFFSET * _OFFSET * k_total)
+    acc = acc - zw * sumqa_ref[...] - za * sum_qw + k * za * zw
+    out = acc.astype(jnp.float32)
+    if use_cv and mode != "exact" and m > 0:
+        # the paper's MAC+ column: rank-1 update + bias-folded C0
+        out = out + (sumx_ref[...].astype(jnp.float32) * c + c0)
+    return out * scale + bias
+
+
+def _scratch(bm: int, bn: int):
+    """acc (bm, bn) plus the per-row sums: offset row, sumx, sumqa."""
+    return [pltpu.VMEM((bm, bn), jnp.int32)] + [
+        pltpu.VMEM((bm, 1), jnp.int32) for _ in range(3)]
+
+
 def _kernel(
     # inputs
     a_ref,  # (bm, bk) uint8 codes
@@ -95,11 +209,12 @@ def _kernel(
     c0_ref,  # (1, bn) f32  CV constant C0
     sum_qw_ref,  # (1, bn) i32  column sums of W codes
     bias_ref,  # (1, bn) f32
-    meta_ref,  # (1, 8) f32: [sa, sw, za, zw, true_k, 0, 0, 0]
+    meta_ref,  # (1, 8) f32: [sa, sw, za, zw, 0, 0, 0, 0]
     # outputs
     out_ref,  # (bm, bn) f32
     # scratch
     acc_ref,  # (bm, bn) i32
+    row_ref,  # (bm, 1) i32
     sumx_ref,  # (bm, 1) i32
     sumqa_ref,  # (bm, 1) i32
     *,
@@ -107,60 +222,44 @@ def _kernel(
     m: int,
     use_cv: bool,
     nk: int,
+    bk: int,
+    k: int,
 ):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        sumx_ref[...] = jnp.zeros_like(sumx_ref)
-        sumqa_ref[...] = jnp.zeros_like(sumqa_ref)
+        for ref in (acc_ref, row_ref, sumx_ref, sumqa_ref):
+            ref[...] = jnp.zeros_like(ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
-
-    acc_ref[...] += _am_tile_acc(a, w, mode, m)
-    sumqa_ref[...] += jnp.sum(a, axis=1, dtype=jnp.int32, keepdims=True)
-    if use_cv and mode != "exact" and m > 0:
-        sumx_ref[...] += jnp.sum(
-            _x_tile(a, mode, m), axis=1, dtype=jnp.int32, keepdims=True
-        )
+    _accumulate_tile(a_ref[...].astype(jnp.int32),
+                     w_ref[...].astype(jnp.int32),
+                     acc_ref, row_ref, sumx_ref, sumqa_ref,
+                     mode=mode, m=m, use_cv=use_cv)
 
     @pl.when(k_step == nk - 1)
-    def _epilogue():
-        sa = meta_ref[0, 0]
-        sw = meta_ref[0, 1]
-        za = meta_ref[0, 2]
-        zw = meta_ref[0, 3]
-        true_k = meta_ref[0, 4]
-
-        out = acc_ref[...].astype(jnp.float32)
-        if use_cv and mode != "exact" and m > 0:
-            # the paper's MAC+ column: rank-1 update + bias-folded C0
-            out = out + sumx_ref[...].astype(jnp.float32) * c_ref[...]
-            out = out + c0_ref[...]
-        # exact gemmlowp zero-point corrections
-        out = out - zw * sumqa_ref[...].astype(jnp.float32)
-        out = out - za * sum_qw_ref[...].astype(jnp.float32)
-        out = out + true_k * za * zw
-        out = out * (sa * sw) + bias_ref[...]
-        out_ref[...] = out
+    def _epi():
+        out_ref[...] = _epilogue(
+            acc_ref, row_ref, sumx_ref, sumqa_ref,
+            c=c_ref[...], c0=c0_ref[...], sum_qw=sum_qw_ref[...],
+            bias=bias_ref[...], scale=meta_ref[0, 0] * meta_ref[0, 1],
+            za=meta_ref[0, 2].astype(jnp.int32),
+            zw=meta_ref[0, 3].astype(jnp.int32),
+            k=k, k_total=nk * bk, mode=mode, m=m, use_cv=use_cv)
 
 
 def _compiler_params(nk: int):
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
     # single K step (decode-specialized tiles): no cross-step accumulator
     # carry, so every grid axis is freely parallel/reorderable
     sem = "parallel" if nk == 1 else "arbitrary"
-    return cls(dimension_semantics=("parallel", "parallel", sem))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", sem))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "mode", "m", "use_cv", "bm", "bn", "bk", "interpret",
+        "mode", "m", "k", "use_cv", "bm", "bn", "bk", "interpret",
     ),
 )
 def approx_matmul_cv(
@@ -173,10 +272,11 @@ def approx_matmul_cv(
     sa: jax.Array,  # scalar f32 activation scale
     sw: jax.Array,  # scalar f32 weight scale
     za: jax.Array,  # scalar i32/f32 activation zero point
-    zw: jax.Array,  # scalar
+    zw: jax.Array,  # scalar (an integer zero point)
     *,
     mode: Mode,
     m: int,
+    k: int,
     use_cv: bool = True,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
@@ -185,8 +285,9 @@ def approx_matmul_cv(
 ) -> jax.Array:
     """Fused quantized approximate matmul; returns float32 (M, N).
 
-    Shapes must be pre-padded to block multiples (ops.py handles padding and
-    arbitrary leading batch dims).
+    Shapes must be pre-padded to block multiples with zero codes (ops.py
+    handles padding and arbitrary leading batch dims); ``k`` is the true
+    fan-in.
     """
     mm, kk = a_q.shape
     kk2, nn = w_q.shape
@@ -195,16 +296,15 @@ def approx_matmul_cv(
         (mm, kk, nn), (bm, bk, bn),
     )
     nk = kk // bk
-    true_k = jnp.float32(kk)  # padding contributes zero codes; za==0 when padded
 
     meta = jnp.zeros((1, 8), jnp.float32)
     meta = meta.at[0, 0].set(jnp.float32(sa))
     meta = meta.at[0, 1].set(jnp.float32(sw))
     meta = meta.at[0, 2].set(jnp.float32(za))
     meta = meta.at[0, 3].set(jnp.float32(zw))
-    meta = meta.at[0, 4].set(true_k)
 
-    kernel = functools.partial(_kernel, mode=mode, m=m, use_cv=use_cv, nk=nk)
+    kernel = functools.partial(_kernel, mode=mode, m=m, use_cv=use_cv,
+                               nk=nk, bk=bk, k=k)
     grid = (mm // bm, nn // bn, nk)
 
     return pl.pallas_call(
@@ -221,11 +321,7 @@ def approx_matmul_cv(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.int32),
-            pltpu.VMEM((bm, 1), jnp.int32),
-            pltpu.VMEM((bm, 1), jnp.int32),
-        ],
+        scratch_shapes=_scratch(bm, bn),
         compiler_params=_compiler_params(nk),
         interpret=interpret,
     )(
@@ -264,6 +360,7 @@ def _fused_kernel(
     out_ref,  # (bm, bn) out_dtype
     # scratch
     acc_ref,  # (bm, bn) i32
+    row_ref,  # (bm, 1) i32
     sumx_ref,  # (bm, 1) i32
     sumqa_ref,  # (bm, 1) i32
     *,
@@ -272,61 +369,49 @@ def _fused_kernel(
     use_cv: bool,
     nk: int,
     bk: int,
+    k: int,
 ):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        sumx_ref[...] = jnp.zeros_like(sumx_ref)
-        sumqa_ref[...] = jnp.zeros_like(sumqa_ref)
+        for ref in (acc_ref, row_ref, sumx_ref, sumqa_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     sa = meta_ref[0, META_SA]
     za = meta_ref[0, META_ZA]
-    true_k = meta_ref[0, META_TRUE_K]
 
     # quantize in-kernel (identical arithmetic to quant.quantize_i32), then
     # zero the K-padding columns: padded float zeros would quantize to the
     # zero-point code, which must not reach acc/sumx/sumqa
     x = x_ref[...].astype(jnp.float32)
     a = jnp.clip(jnp.round(x / sa) + za, 0.0, 255.0).astype(jnp.int32)
-    kcol = k_step * bk + jax.lax.broadcasted_iota(jnp.float32, a.shape, 1)
-    a = jnp.where(kcol < true_k, a, 0)
-    w = w_ref[...].astype(jnp.int32)
-
-    acc_ref[...] += _am_tile_acc(a, w, mode, m)
-    sumqa_ref[...] += jnp.sum(a, axis=1, dtype=jnp.int32, keepdims=True)
-    if use_cv and mode != "exact" and m > 0:
-        sumx_ref[...] += jnp.sum(
-            _x_tile(a, mode, m), axis=1, dtype=jnp.int32, keepdims=True
-        )
+    if k < nk * bk:
+        kcol = k_step * bk + jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+        a = jnp.where(kcol < k, a, 0)
+    _accumulate_tile(a, w_ref[...].astype(jnp.int32),
+                     acc_ref, row_ref, sumx_ref, sumqa_ref,
+                     mode=mode, m=m, use_cv=use_cv)
 
     @pl.when(k_step == nk - 1)
-    def _epilogue():
+    def _epi():
         epi = epi_ref[...]
-        c = epi[EPI_C : EPI_C + 1, :]
-        c0 = epi[EPI_C0 : EPI_C0 + 1, :]
-        sum_qw = epi[EPI_SUM_QW : EPI_SUM_QW + 1, :]
-        bias = epi[EPI_BIAS : EPI_BIAS + 1, :]
-        sw = epi[EPI_SW : EPI_SW + 1, :]
-        zw = epi[EPI_ZW : EPI_ZW + 1, :]
-
-        out = acc_ref[...].astype(jnp.float32)
-        if use_cv and mode != "exact" and m > 0:
-            out = out + sumx_ref[...].astype(jnp.float32) * c
-            out = out + c0
-        # exact gemmlowp zero-point corrections (true_k: K padding excluded)
-        out = out - zw * sumqa_ref[...].astype(jnp.float32)
-        out = out - za * sum_qw
-        out = out + true_k * za * zw
-        out = out * (sa * sw) + bias
+        row = lambda r: epi[r : r + 1, :]
+        out = _epilogue(
+            acc_ref, row_ref, sumx_ref, sumqa_ref,
+            c=row(EPI_C), c0=row(EPI_C0),
+            sum_qw=row(EPI_SUM_QW).astype(jnp.int32), bias=row(EPI_BIAS),
+            scale=sa * row(EPI_SW), za=za.astype(jnp.int32),
+            zw=row(EPI_ZW).astype(jnp.int32),
+            k=k, k_total=nk * bk, mode=mode, m=m, use_cv=use_cv)
         out_ref[...] = out.astype(out_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "mode", "m", "use_cv", "bm", "bn", "bk", "out_dtype", "interpret",
+        "mode", "m", "use_cv", "k", "bm", "bn", "bk", "out_dtype",
+        "interpret",
     ),
 )
 def approx_matmul_cv_fused(
@@ -337,6 +422,7 @@ def approx_matmul_cv_fused(
     *,
     mode: Mode,
     m: int,
+    k: int,
     use_cv: bool = True,
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
@@ -344,7 +430,10 @@ def approx_matmul_cv_fused(
     out_dtype=jnp.float32,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused float->float approximate matmul; returns ``out_dtype`` (M, Nb)."""
+    """Fused float->float approximate matmul; returns ``out_dtype`` (M, Nb).
+
+    ``k`` is the true fan-in: activation columns at or past it are padding.
+    """
     mm, kk = x.shape
     kk2, nn = w_qb.shape
     assert kk == kk2, (x.shape, w_qb.shape)
@@ -355,7 +444,7 @@ def approx_matmul_cv_fused(
     nk = kk // bk
 
     kernel = functools.partial(
-        _fused_kernel, mode=mode, m=m, use_cv=use_cv, nk=nk, bk=bk
+        _fused_kernel, mode=mode, m=m, use_cv=use_cv, nk=nk, bk=bk, k=k
     )
     grid = (mm // bm, nn // bn, nk)
 
@@ -370,11 +459,7 @@ def approx_matmul_cv_fused(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mm, nn), out_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.int32),
-            pltpu.VMEM((bm, 1), jnp.int32),
-            pltpu.VMEM((bm, 1), jnp.int32),
-        ],
+        scratch_shapes=_scratch(bm, bn),
         compiler_params=_compiler_params(nk),
         interpret=interpret,
     )(x, w_qb, epilogue, meta)

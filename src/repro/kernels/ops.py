@@ -1,7 +1,7 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles: arbitrary leading batch dims, padding to block multiples, backend
-selection (real TPU vs interpret-mode CPU validation), and the bridge from
+selection (compiled on TPU, interpret mode on CPU), and the bridge from
 the framework's packed-parameter representation (QuantizedDense) to raw
 kernel operands.
 """
@@ -17,11 +17,21 @@ from repro.core.multipliers import Mode
 from repro.kernels import approx_matmul as _amk
 
 
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted on this backend.
+
+    They compile for TPU and run in interpret mode on the CPU backend (the
+    correctness path); any other backend is an error, never a silent
+    interpreted run.
+    """
+    platform = jax.default_backend()
+    if platform == "tpu":
         return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on TPU or interpreted on CPU, not on "
+        f"{platform!r}")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -90,7 +100,7 @@ def approx_matmul_cv_op(
 ) -> jax.Array:
     """Fused approx-matmul+CV over arbitrary leading dims; returns f32 (..., N)."""
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = interpret_mode()
 
     lead = a_q.shape[:-1]
     kk = a_q.shape[-1]
@@ -102,14 +112,9 @@ def approx_matmul_cv_op(
     a2 = _pad_to(_pad_to(a2, 0, bm_), 1, bk_)
     w2 = _pad_to(_pad_to(w_q, 0, bk_), 1, bn_)
 
-    # NOTE on K padding: padded activation codes are 0, padded weight codes
-    # are 0 — every AM is 0 on zero codes and x(0) = 0, so acc/sumx are
-    # unaffected; sum_qa/sum_qw likewise.  The only k-sensitive term is
-    # k*za*zw, for which the kernel receives the PADDED k and we compensate
-    # here by folding (k_pad - k_true)*za*zw out of the result.
-    k_pad = a2.shape[1]
-    pad_terms = jnp.float32(k_pad - kk) * jnp.float32(za) * jnp.float32(zw)
-
+    # K padding: padded activation and weight codes are 0, every AM is 0 on
+    # zero codes and x(0) = 0, so acc/sumx/sumqa/sum_qw are unaffected; the
+    # kernel takes the true k for its k*za*zw term
     cN = _pad_to(jnp.asarray(c, jnp.float32), 0, bn_)
     c0N = _pad_to(jnp.asarray(c0, jnp.float32), 0, bn_)
     sqwN = _pad_to(jnp.asarray(sum_qw, jnp.int32), 0, bn_)
@@ -132,13 +137,13 @@ def approx_matmul_cv_op(
         jnp.float32(zw),
         mode=mode,
         m=m,
+        k=kk,
         use_cv=use_cv,
         bm=bm_,
         bn=bn_,
         bk=bk_,
         interpret=interpret,
     )
-    out = out - pad_terms * (jnp.float32(sa) * jnp.float32(sw))
     return out[:mm, :nn].reshape(*lead, nn)
 
 
@@ -159,7 +164,7 @@ def quantized_dense_fused_op(
     out at pack time.  Returns ``x.dtype`` (..., n).
     """
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = interpret_mode()
 
     lead = x.shape[:-1]
     kk = x.shape[-1]
@@ -184,6 +189,7 @@ def quantized_dense_fused_op(
         mode=mode,
         m=m,
         use_cv=use_cv,
+        k=blocked.k,
         bm=bm_,
         bn=blocked.bn,
         bk=bk_,
@@ -194,7 +200,8 @@ def quantized_dense_fused_op(
 
 
 def quantized_dense_pallas(x: jax.Array, qd) -> jax.Array:
-    """Bridge: QuantizedDense params + float activations -> fused kernel.
+    """Bridge: packed params (QuantizedDense or a fan-out-fused
+    QuantizedDenseGroup) + float activations -> fused kernel.
 
     Packs carrying the offline-blocked serving layout take the
     float-in/float-out fused kernel (quantize-in-kernel, no per-call padding
@@ -204,11 +211,7 @@ def quantized_dense_pallas(x: jax.Array, qd) -> jax.Array:
     from repro.quant.quantize import quantize
 
     pol = qd.policy
-    if pol.groups != 1:
-        raise NotImplementedError(
-            "grouped CV uses the jnp path (set backend='jnp' for groups > 1)"
-        )
-    if getattr(qd, "blocked", None) is not None:
+    if qd.blocked is not None:
         return quantized_dense_fused_op(
             x, qd.blocked, mode=pol.mode, m=pol.m, use_cv=pol.use_cv)
     a_q = quantize(x, qd.a_qp)

@@ -85,10 +85,8 @@ def _kernel(
 
 
 def _compiler_params():
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams"
-    )
-    return cls(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

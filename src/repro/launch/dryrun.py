@@ -38,7 +38,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, list_archs
 from repro.configs.base import ArchConfig
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import model_flops_estimate, roofline_from_compiled
 from repro.launch.serve import ServeConfig, build_serving_params, make_decode_step, make_prefill_step
 from repro.launch.train import TrainConfig, init_train_state, make_train_step, train_state_shardings
@@ -106,7 +106,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, approx_mode: str = "perfora
         record.update(status="skip", reason=reason)
         return record
 
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             fsdp = cfg.name in ("deepseek-67b", "granite-8b")
             from repro.optim import AdamWConfig

@@ -12,30 +12,22 @@ stays inside a pod on ICI.
 
 from __future__ import annotations
 
-import jax
+from jax.experimental import mesh_utils
+from jax.sharding import Mesh
 
-try:  # jax >= 0.5 spells explicit/auto sharding via AxisType
-    from jax.sharding import AxisType
 
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:  # older jax: meshes are Auto by default, no kwarg
-    _AXIS_KW = lambda n: {}  # noqa: E731
+def _mesh(shape, axes) -> Mesh:
+    # Mesh() keeps every axis in Auto sharding mode (jax.make_mesh would
+    # default to Explicit)
+    return Mesh(mesh_utils.create_device_mesh(shape), axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return _mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU tests (device count set by the test's XLA_FLAGS)."""
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
-
-
-def use_mesh(mesh):
-    """Context manager entering ``mesh``: ``jax.set_mesh`` on new jax, the
-    Mesh object's own context manager on versions that predate it."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    return _mesh(shape, axes)

@@ -147,8 +147,6 @@ def roofline_from_compiled(compiled) -> RooflineTerms:
     from repro.launch.hlo_analysis import analyze_hlo
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", cost.get("bytes_accessed", 0.0)))
 

@@ -993,6 +993,12 @@ def main(argv=None) -> None:
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"device: platform={devices[0].platform} "
+          f"kind={devices[0].device_kind} count={len(devices)}")
     if args.legacy:
         run_legacy(args)
     elif args.fleet:

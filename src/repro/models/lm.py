@@ -709,6 +709,29 @@ def _slot_update(cache_arr: jax.Array, update: jax.Array, starts: jax.Array,
     return jax.vmap(write)(cache_arr, update, starts, n_valid)
 
 
+#: Fewest query columns the slot attention's score and context products
+#: see.  A decode-shaped call has one, and XLA lowers a one-column product
+#: differently from the chunk-shaped call's wider ones: on a TPU v5e the
+#: bfloat16 scores then differ in the last bit, so a decode row's numbers
+#: would depend on the shape of the call it rides in.  Padded to this many
+#: columns, both calls compute each column alike; only the real columns
+#: are kept.
+MIN_QUERY_COLS = 8
+
+
+def _pad_queries(q: jax.Array, positions: jax.Array):
+    """Pad the query axis (1) of ``q`` and of ``positions`` (B, C) up to
+    :data:`MIN_QUERY_COLS`; padded columns repeat the last position, so
+    every padded row attends to at least one key."""
+    pad = MIN_QUERY_COLS - q.shape[1]
+    if pad <= 0:
+        return q, positions
+    widths = [(0, 0)] * q.ndim
+    widths[1] = (0, pad)
+    return (jnp.pad(q, widths),
+            jnp.pad(positions, ((0, 0), (0, pad)), mode="edge"))
+
+
 def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
     """Multi-token slot attention.  h: (B, C, D); lc k/v: (B, Hkv, S, hd);
     positions: (B, C) absolute positions lengths[b] + i."""
@@ -723,16 +746,16 @@ def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
                        lengths, n_valid)
     hq, hkv, d = acfg.n_heads, acfg.kv_heads, acfg.head_dim
     g = hq // hkv
-    qg = q.reshape(b, c, hkv, g, d)
+    qg, q_pos = _pad_queries(q.reshape(b, c, hkv, g, d), positions)
     logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, _from_cache(k_c, q.dtype)) * (
         d**-0.5)
     s = k_c.shape[2]
     # causal + filled-cache combined: key j visible to query i iff j <= pos_i
-    mask = jnp.arange(s)[None, None, :] <= positions[:, :, None]  # (B, C, S)
+    mask = jnp.arange(s)[None, None, :] <= q_pos[:, :, None]  # (B, Cq, S)
     logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
     probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
     ctx = jnp.einsum("bhgqk,bhkd->bqhgd", probs, _from_cache(v_c, q.dtype))
-    y = dense(bp["o"], ctx.reshape(b, c, acfg.q_dim), name="o")
+    y = dense(bp["o"], ctx[:, :c].reshape(b, c, acfg.q_dim), name="o")
     return y, {"k": k_c, "v": v_c}
 
 

@@ -32,7 +32,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 from repro.core.approx_linear import dense, init_dense
@@ -169,7 +168,7 @@ def _moe_ep_psum(p: dict, x_flat: jax.Array, cfg: MoEConfig, mesh) -> jax.Array:
         out = _moe_local(p_loc, xl, cfg, shard_id * e_local, e_local, cap)
         return jax.lax.psum(out, ep)
 
-    return shard_map_compat(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(
@@ -178,4 +177,5 @@ def _moe_ep_psum(p: dict, x_flat: jax.Array, cfg: MoEConfig, mesh) -> jax.Array:
             P(data_axes),  # tokens sharded over data axes
         ),
         out_specs=P(data_axes),
+        check_vma=False,
     )(p["router"], p["experts"], x_flat)

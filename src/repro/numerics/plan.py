@@ -86,7 +86,7 @@ def _packed_bytes(w_shape: tuple[int, ...], policy: ApproxPolicy | None,
     if has_bias:
         per_stack += 4 * n
     total = n_elem + stacks * per_stack  # canonical uint8 pack
-    if policy.backend == "pallas" and policy.is_approx and policy.groups == 1:
+    if policy.backend == "pallas" and policy.is_approx:
         from repro.quant.quantize import EPI_ROWS, META_LEN, serving_blocks
 
         bn, bk = serving_blocks(k, n)

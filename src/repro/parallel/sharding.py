@@ -31,18 +31,6 @@ def _dp_axes(mesh: Mesh):
     return tuple(axes) if axes else None
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version
-    (new: top-level + ``check_vma``; old: experimental + ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def fit_spec(spec: P, shape: tuple[int, ...], mesh: Mesh) -> P:
     """Drop sharding on dims the shape cannot honor (non-divisible/too small)."""
     out = []

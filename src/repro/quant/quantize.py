@@ -67,10 +67,28 @@ def quantize(x, qp: QuantParams) -> jax.Array:
     return quantize_i32(x, qp).astype(jnp.uint8)
 
 
+def _as_f32(x) -> jax.Array:
+    """``x`` in float32, holding exactly the values of its own dtype.
+
+    Under XLA's excess-precision rule a fused producer of a bfloat16 tensor
+    may hand its float32 value straight to the convert back to float32;
+    ``reduce_precision`` pins the rounding, so the codes are the same
+    whether the activations were materialized (as a kernel operand is) or
+    fused away.
+    """
+    x = jnp.asarray(x)
+    xf = x.astype(jnp.float32)
+    if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype.itemsize < 4:
+        fi = jnp.finfo(x.dtype)
+        xf = jax.lax.reduce_precision(xf, exponent_bits=fi.nexp,
+                                      mantissa_bits=fi.nmant)
+    return xf
+
+
 def quantize_i32(x, qp: QuantParams) -> jax.Array:
     """Real -> codes held directly in int32 (skips the uint8 round-trip;
     identical code values to :func:`quantize`, one fewer cast on hot paths)."""
-    q = jnp.round(jnp.asarray(x, jnp.float32) / qp.scale) + qp.zero_point
+    q = jnp.round(_as_f32(x) / qp.scale) + qp.zero_point
     return jnp.clip(q, QMIN, QMAX).astype(jnp.int32)
 
 
@@ -189,7 +207,7 @@ EPI_C, EPI_C0, EPI_SUM_QW, EPI_BIAS, EPI_SW, EPI_ZW = range(6)
 EPI_ROWS = 8
 
 #: Meta-vector slots (per-tensor scalars the fused kernel needs).
-META_SA, META_ZA, META_TRUE_K = range(3)
+META_SA, META_ZA = range(2)
 META_LEN = 8
 
 
@@ -273,7 +291,6 @@ def build_blocked_layout(pack: PackedLinear, a_qp: QuantParams,
     meta = jnp.zeros((META_LEN,), jnp.float32)
     meta = meta.at[META_SA].set(jnp.asarray(a_qp.scale, jnp.float32))
     meta = meta.at[META_ZA].set(jnp.asarray(a_qp.zero_point, jnp.float32))
-    meta = meta.at[META_TRUE_K].set(jnp.float32(k))
     return BlockedPack(w_qb=w_qb, epilogue=epi, meta=meta.reshape(1, META_LEN),
                        k=k, n=n, bk=bk, bn=bn)
 
@@ -387,9 +404,8 @@ def folded_linear(a: jax.Array, fold: dict, mode: am.Mode, m: int,
     GEMMs, one constant add.  Semantics match :func:`quantized_linear` to
     float ulps (see :func:`build_fold`).
     """
-    codes = jnp.clip(
-        jnp.round(jnp.asarray(a, jnp.float32) / fold["sa"]) + fold["za"],
-        QMIN, QMAX)
+    codes = jnp.clip(jnp.round(_as_f32(a) / fold["sa"]) + fold["za"],
+                     QMIN, QMAX)
     y = _f32_dot(codes, fold["A"])
     if "B" in fold:
         scale = float(1 << m)
@@ -437,25 +453,22 @@ def quantized_linear(
     """
     k = a.shape[-1]
     a_i = quantize_i32(a, a_qp)
-    acc = am.approx_matmul(a_i, pack.w_q, mode, m).astype(jnp.float32)
+    acc = am.approx_matmul(a_i, pack.w_q, mode, m)
+    # Exact zero-point corrections (gemmlowp adder-side arithmetic), in
+    # int32: every term is an integer and the bracket's true value is
+    # bounded by 255^2 * k, so it is exact where an f32 sum would cancel.
+    sum_qa = jnp.sum(a_i, axis=-1, dtype=jnp.int32)
+    zw = pack.w_zp.astype(jnp.int32)
+    za = a_qp.zero_point.astype(jnp.int32)
+    acc = acc - zw * sum_qa[..., None] - za * pack.sum_qw + k * za * zw
+    y = acc.astype(jnp.float32)
     if use_cv and mode != "exact" and m > 0:
         const = cv.CVConstants(c=pack.c, c0=pack.c0)
         if groups == 1:
-            acc = acc + cv.cv_term(a_i, const, mode, m)
+            y = y + cv.cv_term(a_i, const, mode, m)
         else:
-            acc = acc + cv.cv_term_grouped(a_i, const, mode, m, groups)
-    # Exact zero-point corrections (gemmlowp adder-side arithmetic).
-    sum_qa = jnp.sum(a_i, axis=-1, dtype=jnp.int32).astype(jnp.float32)
-    zw = pack.w_zp.astype(jnp.float32)
-    za = a_qp.zero_point.astype(jnp.float32)
-    acc = (
-        acc
-        - zw * sum_qa[..., None]
-        - za * pack.sum_qw.astype(jnp.float32)
-        + k * za * zw
-    )
-
-    y = acc * (a_qp.scale * pack.w_scale)
+            y = y + cv.cv_term_grouped(a_i, const, mode, m, groups)
+    y = y * (a_qp.scale * pack.w_scale)
     if pack.bias is not None:
         y = y + pack.bias
     return y

@@ -26,7 +26,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import shard_map_compat
 from jax.sharding import PartitionSpec as P
 
 
@@ -80,11 +79,12 @@ def rs_matmul_overlapped(x: jax.Array, w: jax.Array, mesh, axis: str) -> jax.Arr
             )
         return out.astype(xs.dtype)
 
-    return shard_map_compat(
+    return jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P(*((None,) * (x.ndim - 1) + (axis,))), P(axis, None)),
         out_specs=P(),
+        check_vma=False,
     )(x, w)
 
 
@@ -111,11 +111,12 @@ def compressed_psum(grads: Any, mesh, axis: str) -> Any:
             deq = qs.astype(jnp.float32) * ss.reshape((n,) + (1,) * gl.ndim)
             return jnp.mean(deq, axis=0).astype(gl.dtype)
 
-        return shard_map_compat(
+        return jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=P(*((None,) * g.ndim)),
             out_specs=P(*((None,) * g.ndim)),
+            check_vma=False,
         )(g)
 
     return jax.tree.map(leaf_fn, grads)

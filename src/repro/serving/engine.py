@@ -49,6 +49,15 @@ from repro.serving.scheduler import ScheduledBatch, SlotScheduler
 from repro.serving.telemetry import SpanTracer
 from repro.serving import speculative
 
+#: Compiler options of every serving step.  XLA may otherwise skip a
+#: bfloat16 rounding the program asks for where it fuses the producer into
+#: the consumer (its "excess precision" rule), and what it fuses depends on
+#: the call's shape and on the ops around it.  With every rounding kept, a
+#: row's numbers do not depend on whether a projection runs as an XLA dot
+#: or as a Pallas kernel (on a TPU v5e the two programs otherwise diverge
+#: from the first residual add), nor on how the surrounding ops fuse.
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
 
 def _has_blocked_packs(params) -> bool:
     """True iff any packed leaf ships the offline-blocked Pallas layout
@@ -292,10 +301,12 @@ class ServingEngine:
         if self._paged:
             self._step_fn = jax.jit(
                 lambda p, t, c, nv, bt: decode_slots(p, t, c, nv, mesh=mesh,
-                                                     block_tables=bt))
+                                                     block_tables=bt),
+                compiler_options=STEP_COMPILER_OPTIONS)
         else:
             self._step_fn = jax.jit(
-                lambda p, t, c, nv: decode_slots(p, t, c, nv, mesh=mesh))
+                lambda p, t, c, nv: decode_slots(p, t, c, nv, mesh=mesh),
+                compiler_options=STEP_COMPILER_OPTIONS)
 
     def _bridge_window_samples(self) -> None:
         """Forward windowed metrics samples into the span trace as Chrome

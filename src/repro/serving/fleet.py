@@ -32,16 +32,21 @@ once:
   plus per-replica trace files whose events carry the replica's
   ``engine_id``.
 
-Every replica gets its own single-device mesh (:func:`replica_mesh`), so
-a fleet run exercises the ``decode_slots(..., mesh=)`` plumb-through N
-times per host — the N-meshes-on-one-host shape multi-host placement
-will inherit.
+Replicas are placed round-robin over the host's devices: replica *i*
+(counted across tiers), its copy of the tier's packed parameters and its
+KV cache live on device ``i % n_devices``, behind a single-device mesh
+(:func:`replica_mesh`).  On a one-device host every replica shares it.
 
 Token identity: generation is greedy and numerics live in the pack, so a
 request's output depends only on the tier that served it — a fleet run
 is token-identical to single engines packed per tier serving the same
 requests sequentially (tests/test_fleet.py pins this per routing
-policy).
+policy).  On a TPU this rests on the engine's step keeping every bf16
+rounding (``engine.STEP_COMPILER_OPTIONS``) and on the slot attention
+padding a decode call's queries (``models.lm.MIN_QUERY_COLS``), and it
+holds for packed (quantized) tiers; a float tier, whose float32
+projections feed the layernorms, can still round a row sum differently
+in a decode-shaped and a chunk-shaped call and so diverge.
 """
 
 from __future__ import annotations
@@ -106,18 +111,19 @@ class FleetReplica:
         return self.engine.idle
 
 
-def replica_mesh():
-    """A single-device mesh for one replica (axis ``"model"``, size 1).
+def replica_mesh(device=None):
+    """A single-device mesh for one replica (axis ``"model"``, size 1) on
+    ``device`` (default: the first device).
 
-    Gives every replica the mesh-parameterized ``decode_slots`` path the
-    multi-host fleet will use, while staying a no-op numerically — the
-    regression test in tests/test_decode_consistency.py pins that a
-    single-device mesh is token-identical to the mesh-less path."""
+    Gives every replica the mesh-parameterized ``decode_slots`` path,
+    while staying a no-op numerically — the regression test in
+    tests/test_decode_consistency.py pins that a single-device mesh is
+    token-identical to the mesh-less path."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
 
-    return Mesh(np.asarray(jax.devices()[:1]), ("model",))
+    return Mesh(np.asarray([device or jax.devices()[0]]), ("model",))
 
 
 class FleetRouter:
@@ -338,21 +344,24 @@ class FleetRouter:
 def build_fleet(cfg, float_params, tiers: list[TierConfig],
                 ecfg, pack: Callable, api=None,
                 policy: str = "spec-aware",
-                spill_threshold: int | None = None,
-                mesh_per_replica: bool = True) -> FleetRouter:
+                spill_threshold: int | None = None) -> FleetRouter:
     """Assemble a router over in-process replicas from ONE checkpoint.
 
     ``pack(spec_name) -> (params, numerics_label, spec_or_none)`` builds
     a tier's serving parameters from the shared ``float_params`` (the
     deployment supplies it — normally a ``build_serving_params`` closure,
     see ``repro.launch.serve``).  Packing happens once per tier; the
-    tier's replicas share the packed tree (JAX arrays are immutable), so
-    fleet memory scales with tiers, not replicas.
+    tier's replicas on one device share the packed tree (JAX arrays are
+    immutable), so fleet memory scales with tiers per device.
 
-    Each replica gets its own engine, its own single-device mesh
-    (``mesh_per_replica=False`` drops the mesh for debugging), and an
+    Replica *i* (counted across tiers in declaration order) runs on device
+    ``i % len(jax.devices())``: the tier's packed parameters (one copy per
+    tier and device) and the engine's KV cache are committed there.  Each
+    replica gets its own single-device mesh on that device and an
     ``engine_id`` of ``"<tier>:<i>"`` that its trace events carry.
     """
+    import jax
+
     from repro.models import build_model
     from repro.serving.engine import ServingEngine
 
@@ -362,17 +371,24 @@ def build_fleet(cfg, float_params, tiers: list[TierConfig],
     if len(set(names)) != len(names):
         raise ValueError(f"duplicate tier names: {names}")
     api = api or build_model(cfg)
+    devices = jax.devices()
     replicas: list[FleetReplica] = []
     for tier in tiers:
         params, label, spec = pack(tier.spec)
         exact = tier.exact
         if exact is None:
             exact = spec is None or spec.is_exact  # "float" resolves None
+        on_device = {}
         for i in range(tier.count):
+            dev = devices[len(replicas) % len(devices)]
+            if dev not in on_device:
+                on_device[dev] = jax.device_put(params, dev)
             engine = ServingEngine(
-                cfg, params, ecfg, api=api,
-                mesh=replica_mesh() if mesh_per_replica else None,
+                cfg, on_device[dev], ecfg, api=api, mesh=replica_mesh(dev),
                 numerics=label, engine_id=f"{tier.name}:{i}")
+            # committed like the params, so the first step compiles for the
+            # placement every later step sees
+            engine.pool.cache = jax.device_put(engine.pool.cache, dev)
             replicas.append(FleetReplica(engine, tier, i, exact))
     return FleetRouter(replicas, policy=policy,
                        spill_threshold=spill_threshold)

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.engine import STEP_COMPILER_OPTIONS
 from repro.serving.kv_pool import SlotPool
 from repro.serving.metrics import _merge_moments
 
@@ -83,9 +84,11 @@ class ShadowRunner:
         decode_slots = api.decode_slots
         # one jitted callable, one shape, BOTH packs: params are traced,
         # so primary and shadow structures share it (the speculative-
-        # decode dual-pack mechanism, reused)
+        # decode dual-pack mechanism, reused), compiled as the engine's
+        # step is
         self._fn = jax.jit(
-            lambda p, t, c, nv: decode_slots(p, t, c, nv, mesh=mesh))
+            lambda p, t, c, nv: decode_slots(p, t, c, nv, mesh=mesh),
+            compiler_options=STEP_COMPILER_OPTIONS)
         # accumulated A/B state
         self.sampled = 0
         self.tokens = 0

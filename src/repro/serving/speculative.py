@@ -37,7 +37,9 @@ State before a round: the request has emitted ``g`` tokens, the last one
 4. **Verify.**  ONE chunk-shaped call with the EXACT parameters: verify
    rows carry ``[x, d_1 .. d_k]`` with ``n_valid = k + 1`` (PR 4's
    mixed-batch machinery — decode rows riding the chunk shape — already
-   proved chunk-riding rows token-identical to thin calls), prefill rows
+   proved chunk-riding rows token-identical to thin calls; on a TPU that
+   holds for packed exact params, not for float32 ones, see
+   :mod:`repro.serving.fleet`), prefill rows
    their next prompt chunk, plain rows their one token.  Column ``i``'s
    argmax is the exact model's greedy token ``v_{i+1}`` after input ``i``.
 5. **Accept.**  ``j`` = longest prefix with ``v_i == d_i``.  The emission

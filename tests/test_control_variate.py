@@ -9,10 +9,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without the wheel: deterministic fallback
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import control_variate as cv
 from repro.core import multipliers as am

@@ -116,3 +116,31 @@ def test_int8_kv_cache_decode_close():
         err = float(jnp.abs(logits - ref[:, i]).max())
         scale = float(jnp.abs(ref[:, i]).max())
         assert err < 0.05 * scale + 0.05, (i, err, scale)
+
+
+def test_slot_decode_query_padding_keeps_logits(monkeypatch):
+    """The slot attention pads a decode-shaped call's query axis to
+    ``MIN_QUERY_COLS`` columns; the padding must change neither the real
+    column's logits nor the K/V the call writes."""
+    from repro.models import lm
+
+    cfg = dataclasses.replace(get_config("olmo-1b-reduced"),
+                              compute_dtype="float32")
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    cache = api.init_slot_cache(2, 32, jnp.float32)
+    _, cache = api.decode_slots(
+        params, jnp.asarray(rng.integers(0, cfg.vocab, (2, 16)), jnp.int32),
+        cache, jnp.asarray([10, 5], np.int32))
+    tok = jnp.asarray(rng.integers(0, cfg.vocab, (2, 1)), jnp.int32)
+    nv = jnp.asarray([1, 1], np.int32)
+    assert lm.MIN_QUERY_COLS > 1
+    padded, padded_cache = api.decode_slots(params, tok, cache, nv)
+    monkeypatch.setattr(lm, "MIN_QUERY_COLS", 1)
+    plain, plain_cache = api.decode_slots(params, tok, cache, nv)
+    np.testing.assert_allclose(np.asarray(padded), np.asarray(plain),
+                               rtol=1e-5, atol=1e-5)
+    for key in ("k", "v", "lengths"):
+        np.testing.assert_array_equal(np.asarray(padded_cache[key]),
+                                      np.asarray(plain_cache[key]))

@@ -17,6 +17,11 @@ packed per tier, under every routing policy.
 """
 
 import dataclasses
+import json
+import os
+import subprocess
+import sys
+import textwrap
 
 import jax
 import numpy as np
@@ -29,6 +34,8 @@ from repro.models import build_model
 from repro.numerics import get_preset
 from repro.serving import (FleetReplica, FleetRouter, ServingEngine,
                            TierConfig, build_fleet)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # ---------------------------------------------------------------------------
 # router units (no model)
@@ -342,3 +349,82 @@ def test_fleet_share_prefixes_cross_replica(olmo, packs):
     assert snap["tiers"]["int8"]["prefix_imports"] > 0
     assert snap["fleet"]["prefix_imports"] == \
         snap["tiers"]["int8"]["prefix_imports"]
+
+
+# ---------------------------------------------------------------------------
+# placement: one replica per device (virtual CPU devices, one subprocess per
+# device count so this process keeps its single device)
+# ---------------------------------------------------------------------------
+
+FLEET_PLACEMENT = textwrap.dedent("""
+    import os, sys
+    n_dev = int(sys.argv[1])
+    os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
+    import dataclasses, json
+    import jax
+    import numpy as np
+    from repro.configs import get_config
+    from repro.configs.base import EngineConfig
+    from repro.launch.serve import ServeConfig, build_serving_params
+    from repro.models import build_model
+    from repro.numerics import get_preset
+    from repro.serving import TierConfig, build_fleet
+
+    assert len(jax.devices()) == n_dev
+    cfg = dataclasses.replace(get_config("olmo-1b-reduced"),
+                              compute_dtype="float32")
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    names = ("int8", "serve-default")
+    packs = {n: build_serving_params(params, cfg,
+                                     ServeConfig(spec=get_preset(n)))
+             for n in names}
+    ecfg = EngineConfig(slots=3, max_len=64, prefill_chunk=16,
+                        cache_dtype="float32", kv_layout="contiguous")
+    rng = np.random.default_rng(6)
+    jobs = [(rng.integers(0, cfg.vocab, int(rng.integers(4, 22))).tolist(), 5)
+            for _ in range(8)]
+
+    def homes(fleet):
+        out = []
+        for rep in fleet.replicas:
+            on = lambda tree: {d for x in jax.tree.leaves(tree)
+                               for d in x.devices()}
+            p_dev, c_dev = on(rep.engine.params), on(rep.engine.pool.cache)
+            assert len(p_dev) == 1 and p_dev == c_dev, (rep.replica_id,
+                                                        p_dev, c_dev)
+            out.append(p_dev.pop().id)
+        return out
+
+    fleet = build_fleet(cfg, params, [TierConfig(n, n, count=2)
+                                      for n in names], ecfg,
+                        pack=lambda n: (packs[n], n, get_preset(n)), api=api)
+    placed_on = homes(fleet)  # before the first step
+    placed = [fleet.submit(p, g, klass="bulk" if i % 2 else "latency")
+              for i, (p, g) in enumerate(jobs)]
+    fleet.drain()
+    assert homes(fleet) == placed_on  # and after serving
+    assert {r.fleet_replica for r in placed} == {
+        rep.replica_id for rep in fleet.replicas}
+    print(json.dumps({"devices": placed_on,
+                      "tokens": [[r.fleet_replica, r.generated]
+                                 for r in placed]}))
+""")
+
+
+def _fleet_placement(n_dev: int) -> dict:
+    env = dict(os.environ, PYTHONPATH="src")
+    out = subprocess.run([sys.executable, "-c", FLEET_PLACEMENT, str(n_dev)],
+                         capture_output=True, text=True, env=env,
+                         cwd=REPO, timeout=280)
+    assert out.returncode == 0, out.stderr[-3000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_fleet_places_one_replica_per_device():
+    """Four replicas on four devices: each replica's params and KV cache
+    sit on its own device, and the tokens match the one-device fleet."""
+    four, one = _fleet_placement(4), _fleet_placement(1)
+    assert four["devices"] == [0, 1, 2, 3]
+    assert one["devices"] == [0, 0, 0, 0]
+    assert four["tokens"] == one["tokens"]

@@ -4,14 +4,20 @@ Per the deliverable: sweep shapes/dtypes/modes and assert_allclose against
 the ref.py oracles.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.approx_linear import QuantizedDense
+from repro.core.policy import paper_policies
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rwkv6_scan import rwkv6_scan
+from repro.quant.quantize import (PackedLinear, QuantParams,
+                                  build_blocked_layout, quantized_linear)
 
 RNG = np.random.default_rng(7)
 
@@ -64,6 +70,49 @@ def test_approx_matmul_batched_leading_dims():
         a_q, w_q, c, c0, sqw, bias, 0.01, 0.02, 1.0, 2.0,
         mode="recursive", m=3, interpret=True))
     np.testing.assert_allclose(out.reshape(-1, 24), flat, rtol=1e-5, atol=1e-3)
+
+
+def _transparent_dense(k: int, n: int, policy) -> QuantizedDense:
+    """A pack whose float epilogue is exact: scales 1, integer zero points
+    (za = 128, zw = 7), CV constants C = 1 and C0 = 0.  Its output is the
+    integer bracket  acc - zw*sumqa - za*sum_qw + k*za*zw  (plus sum_x with
+    CV), so any slip in the int8 operand shift, its fold-back or the
+    zero-point terms shows as a mismatch, not as float noise."""
+    w_q = jnp.asarray(RNG.integers(0, 256, (k, n)), jnp.uint8)
+    pack = PackedLinear(
+        w_q=w_q, w_scale=jnp.float32(1.0), w_zp=jnp.int32(7),
+        sum_qw=jnp.sum(w_q.astype(jnp.int32), axis=0),
+        c=jnp.ones((n,), jnp.float32), c0=jnp.zeros((n,), jnp.float32),
+        bias=None)
+    a_qp = QuantParams(jnp.float32(1.0), jnp.int32(128))
+    return QuantizedDense(pack=pack, a_qp=a_qp, policy=policy,
+                          blocked=build_blocked_layout(pack, a_qp))
+
+
+@pytest.mark.parametrize("use_cv", [True, False])
+@pytest.mark.parametrize("policy", paper_policies(backend="pallas"),
+                         ids=lambda p: f"{p.mode}{p.m}")
+@pytest.mark.parametrize("k,rows", [(300, 5), (1024, 130)])
+def test_int8_operand_kernels_bit_exact(policy, use_cv, k, rows):
+    """Both CV kernels, fed int8 operands, reproduce quantized_linear's
+    integer result bit for bit across the paper grid at fan-ins above 258
+    (K padding at 300, several K tiles at 1024), on decode- and
+    prefill-shaped rows, with activation codes over the whole 0..255
+    range (both int8 extremes after the shift) and nonzero zero points."""
+    policy = dataclasses.replace(policy, use_cv=use_cv)
+    qd = _transparent_dense(k, 136, policy)
+    codes = RNG.integers(0, 256, (rows, k))
+    codes[0, :2] = (0, 255)
+    x = jnp.asarray(codes - 128, jnp.float32)
+    want = np.asarray(quantized_linear(x, qd.pack, qd.a_qp, policy.mode,
+                                       policy.m, use_cv=use_cv))
+    fused = ops.quantized_dense_fused_op(x, qd.blocked, mode=policy.mode,
+                                         m=policy.m, use_cv=use_cv,
+                                         interpret=True)
+    plain = ops.quantized_dense_pallas(x, dataclasses.replace(qd,
+                                                              blocked=None))
+    np.testing.assert_array_equal(np.asarray(fused), want)
+    np.testing.assert_array_equal(np.asarray(plain), want)
 
 
 @pytest.mark.parametrize("t,dk,dv", [(64, 64, 64), (96, 32, 32)])

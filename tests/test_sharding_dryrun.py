@@ -26,7 +26,7 @@ def test_fit_spec_drops_indivisible():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.parallel import fit_spec
 
     mesh = make_test_mesh((1,), ("model",))
@@ -43,7 +43,7 @@ MINI_DRYRUN = textwrap.dedent("""
     import dataclasses
     import jax, jax.numpy as jnp
     from repro.configs import get_config
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.launch.train import (TrainConfig, init_train_state,
                                     make_train_step, train_state_shardings)
     from repro.parallel import batch_shardings
@@ -54,7 +54,7 @@ MINI_DRYRUN = textwrap.dedent("""
     mesh = make_test_mesh((2, 4), ("data", "model"))
     if cfg.mlp == "moe":
         cfg = dataclasses.replace(cfg, moe_impl="ep_psum")
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         tcfg = TrainConfig()
         step = make_train_step(cfg, tcfg, mesh=mesh)
         abstract = jax.eval_shape(lambda: init_train_state(cfg, tcfg, jax.random.PRNGKey(0)))
@@ -72,8 +72,6 @@ MINI_DRYRUN = textwrap.dedent("""
         jitted = jax.jit(step, in_shardings=(st_sh, b_sh), out_shardings=(st_sh, None))
         compiled = jitted.lower(abstract, batch_abs).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0]
         print("MINI_DRYRUN_OK", ARCH, int(cost.get("flops", 0)) > 0)
 """)
 
@@ -90,7 +88,7 @@ EP_EQUIV = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.nn import moe as moelib
 
     mesh = make_test_mesh((2, 4), ("data", "model"))
@@ -98,7 +96,7 @@ EP_EQUIV = textwrap.dedent("""
                            n_shared=1, impl="ep_psum", capacity_factor=8.0)
     p = moelib.init_moe(jax.random.PRNGKey(0), cfg)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 12, 32))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep = jax.jit(lambda p, x: moelib.moe_apply(p, x, cfg, mesh=mesh))(p, x)
     y_local = moelib.moe_apply(p, x, dataclasses.replace(cfg, impl="local"))
     diff = float(jnp.abs(y_ep - y_local).max())
@@ -115,13 +113,13 @@ OVERLAP = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
-    from repro.launch.mesh import make_test_mesh, use_mesh
+    from repro.launch.mesh import make_test_mesh
     from repro.runtime.overlap import rs_matmul_overlapped, compressed_psum
 
     mesh = make_test_mesh((4,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(0), (6, 16))
     w = jax.random.normal(jax.random.PRNGKey(1), (16, 8))
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         y = jax.jit(lambda x, w: rs_matmul_overlapped(x, w, mesh, "model"))(x, w)
     assert float(jnp.abs(y - x @ w).max()) < 1e-4
     print("OVERLAP_OK")
