@@ -1,0 +1,8 @@
+"""Model step: device time of one decode-shaped call of the jitted step, mean
+over the traced window, ms (profiler trace)."""
+
+from bench.readings import step_ms
+
+
+def read(run):
+    return step_ms(run, "decode")
