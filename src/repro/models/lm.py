@@ -739,23 +739,31 @@ def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
 
     acfg = cfg.attn_config()
     b, c, _ = h.shape
-    q, k, v = attn_lib._project_qkv(bp, h, acfg, attn_lib._angles(acfg, positions))
-    k_c = _slot_update(lc["k"], _to_cache(jnp.moveaxis(k, 1, 2), lc["k"].dtype),
-                       lengths, n_valid)
-    v_c = _slot_update(lc["v"], _to_cache(jnp.moveaxis(v, 1, 2), lc["v"].dtype),
-                       lengths, n_valid)
+    with jax.named_scope("qkv"):
+        q, k, v = attn_lib._project_qkv(bp, h, acfg,
+                                        attn_lib._angles(acfg, positions))
+    with jax.named_scope("kv_write"):
+        k_c = _slot_update(lc["k"],
+                           _to_cache(jnp.moveaxis(k, 1, 2), lc["k"].dtype),
+                           lengths, n_valid)
+        v_c = _slot_update(lc["v"],
+                           _to_cache(jnp.moveaxis(v, 1, 2), lc["v"].dtype),
+                           lengths, n_valid)
     hq, hkv, d = acfg.n_heads, acfg.kv_heads, acfg.head_dim
     g = hq // hkv
-    qg, q_pos = _pad_queries(q.reshape(b, c, hkv, g, d), positions)
-    logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg, _from_cache(k_c, q.dtype)) * (
-        d**-0.5)
-    s = k_c.shape[2]
-    # causal + filled-cache combined: key j visible to query i iff j <= pos_i
-    mask = jnp.arange(s)[None, None, :] <= q_pos[:, :, None]  # (B, Cq, S)
-    logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
-    ctx = jnp.einsum("bhgqk,bhkd->bqhgd", probs, _from_cache(v_c, q.dtype))
-    y = dense(bp["o"], ctx[:, :c].reshape(b, c, acfg.q_dim), name="o")
+    with jax.named_scope("attn"):
+        qg, q_pos = _pad_queries(q.reshape(b, c, hkv, g, d), positions)
+        logits = jnp.einsum("bqhgd,bhkd->bhgqk", qg,
+                            _from_cache(k_c, q.dtype)) * (d**-0.5)
+        s = k_c.shape[2]
+        # causal + filled-cache combined: key j visible to query i iff
+        # j <= pos_i
+        mask = jnp.arange(s)[None, None, :] <= q_pos[:, :, None]  # (B, Cq, S)
+        logits = jnp.where(mask[:, None, None], logits, -jnp.inf)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(q.dtype)
+        ctx = jnp.einsum("bhgqk,bhkd->bqhgd", probs, _from_cache(v_c, q.dtype))
+    with jax.named_scope("o_proj"):
+        y = dense(bp["o"], ctx[:, :c].reshape(b, c, acfg.q_dim), name="o")
     return y, {"k": k_c, "v": v_c}
 
 
@@ -763,59 +771,84 @@ def _mla_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
     """Weight-absorbed MLA slot attention over the pooled latent cache."""
     mcfg = cfg.mla_config()
     b, c, _ = h.shape
-    q_nope, q_rope = attn_lib._mla_q(bp, h, mcfg, positions)
-    latent_t, k_rope_t = attn_lib._mla_latent(bp, h, mcfg, positions)
-    lat_c = _slot_update(lc["latent"], latent_t.astype(lc["latent"].dtype),
-                         lengths, n_valid)
-    rope_c = _slot_update(lc["rope"], k_rope_t.astype(lc["rope"].dtype),
-                          lengths, n_valid)
+    with jax.named_scope("qkv"):
+        q_nope, q_rope = attn_lib._mla_q(bp, h, mcfg, positions)
+        latent_t, k_rope_t = attn_lib._mla_latent(bp, h, mcfg, positions)
+    with jax.named_scope("kv_write"):
+        lat_c = _slot_update(lc["latent"],
+                             latent_t.astype(lc["latent"].dtype),
+                             lengths, n_valid)
+        rope_c = _slot_update(lc["rope"], k_rope_t.astype(lc["rope"].dtype),
+                              lengths, n_valid)
 
-    w_b = bp["kv_b"]["w"].reshape(
-        mcfg.kv_lora_rank, mcfg.n_heads, mcfg.qk_nope_dim + mcfg.v_head_dim
-    )
-    w_uk, w_uv = w_b[..., : mcfg.qk_nope_dim], w_b[..., mcfg.qk_nope_dim :]
-    q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_uk)
-    scale = mcfg.qk_head_dim**-0.5
-    lat = lat_c.astype(h.dtype)
-    logits = (
-        jnp.einsum("bqhr,bkr->bhqk", q_lat, lat)
-        + jnp.einsum("bqhd,bkd->bhqk", q_rope, rope_c.astype(h.dtype))
-    ) * scale
-    s = lat_c.shape[1]
-    mask = jnp.arange(s)[None, None, :] <= positions[:, :, None]  # (B, C, S)
-    logits = jnp.where(mask[:, None], logits, -jnp.inf)
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(h.dtype)
-    ctx_lat = jnp.einsum("bhqk,bkr->bqhr", probs, lat)
-    ctx = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_uv)
-    y = dense(bp["o"], ctx.reshape(b, c, -1), name="o")
+    with jax.named_scope("attn"):
+        w_b = bp["kv_b"]["w"].reshape(
+            mcfg.kv_lora_rank, mcfg.n_heads,
+            mcfg.qk_nope_dim + mcfg.v_head_dim)
+        w_uk, w_uv = w_b[..., : mcfg.qk_nope_dim], w_b[..., mcfg.qk_nope_dim:]
+        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_uk)
+        scale = mcfg.qk_head_dim**-0.5
+        lat = lat_c.astype(h.dtype)
+        logits = (
+            jnp.einsum("bqhr,bkr->bhqk", q_lat, lat)
+            + jnp.einsum("bqhd,bkd->bhqk", q_rope, rope_c.astype(h.dtype))
+        ) * scale
+        s = lat_c.shape[1]
+        mask = jnp.arange(s)[None, None, :] <= positions[:, :, None]
+        logits = jnp.where(mask[:, None], logits, -jnp.inf)
+        probs = jax.nn.softmax(logits.astype(jnp.float32), -1).astype(h.dtype)
+        ctx_lat = jnp.einsum("bhqk,bkr->bqhr", probs, lat)
+        ctx = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_uv)
+    with jax.named_scope("o_proj"):
+        y = dense(bp["o"], ctx.reshape(b, c, -1), name="o")
     return y, {"latent": lat_c, "rope": rope_c}
+
+
+#: Named scopes of the served step (``jax.named_scope``): every operation of
+#: ``decode_slots`` falls under one of them, so a device trace, through
+#: each op's ``op_name``, says which layer of the step it belongs to.  An
+#: op under ``layer_stack`` and no inner scope is the layer scan's own
+#: slicing and restacking of the stacked KV cache and weights; ``cv`` (the
+#: control-variate correction, :func:`repro.quant.quantize.quantized_linear`)
+#: nests under the dense scope that calls it.
+STEP_SCOPES = ("embed", "attn_norm", "qkv", "kv_write", "attn", "o_proj",
+               "mlp_norm", "mlp_in", "mlp_out", "final_norm", "head",
+               "layer_stack", "cv")
 
 
 def _block_decode_slots(bp: dict, x, lc: dict, lengths, n_valid,
                         cfg: ArchConfig, positions, mesh, block_tables=None):
-    h = apply_norm(cfg.norm, bp["attn_norm"], x)
+    with jax.named_scope("attn_norm"):
+        h = apply_norm(cfg.norm, bp["attn_norm"], x)
     pool_lc = None
     if block_tables is not None:
         # paged layout: gather each slot's blocks into the contiguous view
         # the slot attention expects, run it unchanged, scatter back
         pool_lc = lc
-        lc = {k: _paged_gather(v, block_tables) for k, v in lc.items()}
+        with jax.named_scope("kv_write"):
+            lc = {k: _paged_gather(v, block_tables) for k, v in lc.items()}
     if cfg.attn == "mla":
         a, new = _mla_slots(bp["attn"], h, lc, lengths, n_valid, cfg, positions)
     else:
         a, new = _gqa_slots(bp["attn"], h, lc, lengths, n_valid, cfg, positions)
     if pool_lc is not None:
-        new = {k: _paged_scatter(pool_lc[k], block_tables, v)
-               for k, v in new.items()}
-    x = (x + a).astype(x.dtype)
-    h = apply_norm(cfg.norm, bp["mlp_norm"], x)
+        with jax.named_scope("kv_write"):
+            new = {k: _paged_scatter(pool_lc[k], block_tables, v)
+                   for k, v in new.items()}
+    with jax.named_scope("o_proj"):
+        x = (x + a).astype(x.dtype)
+    with jax.named_scope("mlp_norm"):
+        h = apply_norm(cfg.norm, bp["mlp_norm"], x)
     if cfg.mlp == "moe" and "router" in bp["mlp"]:
-        m = moe_lib.moe_apply(bp["mlp"], h, cfg.moe_config(), mesh=mesh)
+        # the routed experts' in and out products are not told apart
+        with jax.named_scope("mlp_in"):
+            m = moe_lib.moe_apply(bp["mlp"], h, cfg.moe_config(), mesh=mesh)
     elif cfg.mlp == "gelu":
         m = gelu_mlp(bp["mlp"], h)
     else:
         m = swiglu(bp["mlp"], h)
-    return (x + m).astype(x.dtype), new
+    with jax.named_scope("mlp_out"):
+        return (x + m).astype(x.dtype), new
 
 
 def decode_slots(params: Params, tokens: jax.Array, cache: dict,
@@ -872,18 +905,22 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
     n_valid = jnp.asarray(n_valid, jnp.int32)
     if block_tables is not None:
         block_tables = jnp.asarray(block_tables, jnp.int32)
-    positions = lengths[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    x = embed(params["embed"], tokens).astype(cdt)
+    with jax.named_scope("embed"):
+        positions = lengths[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+        x = embed(params["embed"], tokens).astype(cdt)
     new_cache = dict(cache)
 
     dense_keys = ("latent", "rope") if cfg.attn == "mla" else ("k", "v")
     for i, bp in enumerate(params.get("dense_blocks", [])):
-        lc = {k: cache[f"dense_{k}"][i] for k in dense_keys}
+        with jax.named_scope("kv_write"):
+            lc = {k: cache[f"dense_{k}"][i] for k in dense_keys}
         with observers.scope("dense_blocks", i):
             x, new = _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
                                          positions, mesh, block_tables)
-        for k in dense_keys:
-            new_cache[f"dense_{k}"] = new_cache[f"dense_{k}"].at[i].set(new[k])
+        with jax.named_scope("kv_write"):
+            for k in dense_keys:
+                new_cache[f"dense_{k}"] = (
+                    new_cache[f"dense_{k}"].at[i].set(new[k]))
 
     layer_keys = [k for k in ("latent", "rope", "k", "v") if k in cache]
     lcs = {k: cache[k] for k in layer_keys}
@@ -907,12 +944,16 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
             return _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
                                        positions, mesh, block_tables)
 
-        x, new_layers = jax.lax.scan(body, x, (params["blocks"], lcs))
+        with jax.named_scope("layer_stack"):
+            x, new_layers = jax.lax.scan(body, x, (params["blocks"], lcs))
     new_cache.update(new_layers)
-    new_cache["lengths"] = lengths + n_valid
+    with jax.named_scope("kv_write"):
+        new_cache["lengths"] = lengths + n_valid
 
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    logits = _logits_head(params, x)
+    with jax.named_scope("final_norm"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+    with jax.named_scope("head"):
+        logits = _logits_head(params, x)
     return logits, new_cache
 
 

@@ -104,15 +104,18 @@ def init_swiglu(key, d: int, ff: int, dtype=jnp.float32) -> dict:
 
 
 def swiglu(p: dict, x: jax.Array) -> jax.Array:
-    if "gateup" in p:  # fan-out-fused serving pack: one wide-N call
-        from repro.core.approx_linear import dense_group
+    with jax.named_scope("mlp_in"):
+        if "gateup" in p:  # fan-out-fused serving pack: one wide-N call
+            from repro.core.approx_linear import dense_group
 
-        gu = dense_group(p["gateup"], x)
-        g, u = gu["gate"], gu["up"]
-    else:
-        g = dense(p["gate"], x, name="gate")
-        u = dense(p["up"], x, name="up")
-    return dense(p["down"], jax.nn.silu(g) * u, name="down")
+            gu = dense_group(p["gateup"], x)
+            g, u = gu["gate"], gu["up"]
+        else:
+            g = dense(p["gate"], x, name="gate")
+            u = dense(p["up"], x, name="up")
+        h = jax.nn.silu(g) * u
+    with jax.named_scope("mlp_out"):
+        return dense(p["down"], h, name="down")
 
 
 def init_gelu_mlp(key, d: int, ff: int, dtype=jnp.float32) -> dict:
@@ -124,7 +127,10 @@ def init_gelu_mlp(key, d: int, ff: int, dtype=jnp.float32) -> dict:
 
 
 def gelu_mlp(p: dict, x: jax.Array) -> jax.Array:
-    return dense(p["down"], jax.nn.gelu(dense(p["up"], x, name="up")), name="down")
+    with jax.named_scope("mlp_in"):
+        h = jax.nn.gelu(dense(p["up"], x, name="up"))
+    with jax.named_scope("mlp_out"):
+        return dense(p["down"], h, name="down")
 
 
 # ---------------------------------------------------------------------------

@@ -464,10 +464,12 @@ def quantized_linear(
     y = acc.astype(jnp.float32)
     if use_cv and mode != "exact" and m > 0:
         const = cv.CVConstants(c=pack.c, c0=pack.c0)
-        if groups == 1:
-            y = y + cv.cv_term(a_i, const, mode, m)
-        else:
-            y = y + cv.cv_term_grouped(a_i, const, mode, m, groups)
+        # the scope a device trace reads the CV's share of the step by
+        with jax.named_scope("cv"):
+            if groups == 1:
+                y = y + cv.cv_term(a_i, const, mode, m)
+            else:
+                y = y + cv.cv_term_grouped(a_i, const, mode, m, groups)
     y = y * (a_qp.scale * pack.w_scale)
     if pack.bias is not None:
         y = y + pack.bias

@@ -46,8 +46,8 @@ from repro.serving.metrics import EngineMetrics
 from repro.serving.request import (AdmissionController, Request, RequestQueue,
                                    RequestState)
 from repro.serving.scheduler import ScheduledBatch, SlotScheduler
+from repro.serving import speculative, telemetry
 from repro.serving.telemetry import SpanTracer
-from repro.serving import speculative
 
 #: Compiler options of every serving step.  XLA may otherwise skip a
 #: bfloat16 rounding the program asks for where it fuses the producer into
@@ -294,19 +294,26 @@ class ServingEngine:
         self.active: dict[int, Request] = {}
         self._rid = itertools.count()
         decode_slots = self.api.decode_slots
-        # one jitted callable, two shapes ever: (slots, 1) and (slots, chunk).
-        # The paged layout adds the fixed-shape block-table argument — its
-        # CONTENT changes per admission, its shape never, so the invariant
-        # holds per layout.
-        if self._paged:
-            self._step_fn = jax.jit(
-                lambda p, t, c, nv, bt: decode_slots(p, t, c, nv, mesh=mesh,
-                                                     block_tables=bt),
-                compiler_options=STEP_COMPILER_OPTIONS)
-        else:
-            self._step_fn = jax.jit(
-                lambda p, t, c, nv: decode_slots(p, t, c, nv, mesh=mesh),
-                compiler_options=STEP_COMPILER_OPTIONS)
+
+        def step_fn(name: str):
+            if self._paged:
+                def fn(p, t, c, nv, bt):
+                    return decode_slots(p, t, c, nv, mesh=mesh,
+                                        block_tables=bt)
+            else:
+                def fn(p, t, c, nv):
+                    return decode_slots(p, t, c, nv, mesh=mesh)
+
+            fn.__name__ = fn.__qualname__ = name
+            return jax.jit(fn, compiler_options=STEP_COMPILER_OPTIONS)
+
+        # one jitted callable per shape, each compiled once: (slots, 1) and
+        # (slots, chunk), so a device trace names the step that ran
+        # (jit_engine_decode_step, jit_engine_chunk_step).  The paged layout
+        # adds the fixed-shape block-table argument — its CONTENT changes
+        # per admission, its shape never, so the invariant holds per layout.
+        self._step_fns = {"decode": step_fn("engine_decode_step"),
+                          "chunk": step_fn("engine_chunk_step")}
 
     def _bridge_window_samples(self) -> None:
         """Forward windowed metrics samples into the span trace as Chrome
@@ -371,54 +378,71 @@ class ServingEngine:
     def step(self) -> list[Request]:
         """One engine iteration; returns requests that finished in it
         (including queued requests evicted by an expired deadline — they
-        are terminal without ever touching a slot)."""
+        are terminal without ever touching a slot).
+
+        The iteration is one ``engine.step`` span with its phases
+        (``schedule``, ``dispatch``, ``fetch``, ``emit``, ``account``) as
+        children; see :mod:`repro.serving.telemetry`."""
+        with telemetry.spans(self.tracer)("step", step=self._steps) as st:
+            return self._step(st)
+
+    def _step(self, st: telemetry.Span) -> list[Request]:
         tr = self.tracer
-        expired = self.scheduler.purge_expired(self.queue, self.metrics,
-                                               tracer=tr)
-        admitted = self.scheduler.admit(self.queue, self.pool, self.active,
-                                        self.metrics, tracer=tr)
-        for r in admitted:
-            if r.prefix_hit_tokens:
-                self.metrics.prefix_hits += 1
-                self.metrics.prefix_hit_tokens += r.prefix_hit_tokens
-                if tr is not None:
-                    tr.record("prefix_hit", rid=r.rid,
-                              hit_tokens=r.prefix_hit_tokens)
+        span = telemetry.spans(tr)
+        with span("schedule", queued=len(self.queue)) as sc:
+            expired = self.scheduler.purge_expired(self.queue, self.metrics,
+                                                   tracer=tr)
+            admitted = self.scheduler.admit(self.queue, self.pool,
+                                            self.active, self.metrics,
+                                            tracer=tr)
+            for r in admitted:
+                if r.prefix_hit_tokens:
+                    self.metrics.prefix_hits += 1
+                    self.metrics.prefix_hit_tokens += r.prefix_hit_tokens
+                    if tr is not None:
+                        tr.record("prefix_hit", rid=r.rid,
+                                  hit_tokens=r.prefix_hit_tokens)
+            if self._spec_k:
+                # every turn goes through the speculative round — including
+                # turns with zero spec rows — so plain decode rows always
+                # ride chunk-shaped exact calls and the exact parameters
+                # never meet the thin shape (two compiled shapes total, same
+                # as plain serving: draft structure x thin + exact
+                # structure x chunk)
+                rnd = speculative.plan_round(self.active, self._spec_k,
+                                             self.ecfg.prefill_chunk)
+            else:
+                batch = self.scheduler.next_batch(self.active)
+            sc.set(admitted=len(admitted))
         if self._spec_k:
-            # every turn goes through the speculative round — including
-            # turns with zero spec rows — so plain decode rows always ride
-            # chunk-shaped exact calls and the exact parameters never meet
-            # the thin shape (two compiled shapes total, same as plain
-            # serving: draft structure x thin + exact structure x chunk)
-            rnd = speculative.plan_round(self.active, self._spec_k,
-                                         self.ecfg.prefill_chunk)
             if rnd is None:
                 return expired
-            return expired + self._speculative_step(rnd)
-        batch = self.scheduler.next_batch(self.active)
+            return expired + self._speculative_step(rnd, st)
         if batch is None:
             return expired
+        if st.recording:
+            st.set(shape=_shape(batch), **_row_counts(batch))
         # arm the throughput clock BEFORE the dispatch: warmup between
         # construction and the first served batch stays excluded, but the
         # first measured step's own wall time is inside the window
         self.metrics.start_clock()
-        t0 = time.perf_counter() if tr is not None else 0.0
-        tables = None
-        if self._paged:
-            # copy-on-write barrier: every block this batch writes must be
-            # uniquely owned before the jitted step sees the tables
-            cow0 = self.pool.cow_copies if tr is not None else 0
-            for slot, nv in enumerate(batch.n_valid):
-                self.pool.ensure_writable(slot, int(nv))
-            self.pool.flush_copies()
-            if tr is not None and self.pool.cow_copies > cow0:
-                tr.record("cow_copy", copies=self.pool.cow_copies - cow0)
-            tables = self.pool.block_tables_array()
-        cache_before = self.pool.cache
-        logits, new_cache = self._dispatch(self.params, batch, tables)
-        self.pool.update(new_cache)
-        if self._paged:
-            self.pool.advance(batch.n_valid)
+        with span("dispatch") as dsp:
+            tables = None
+            if self._paged:
+                # copy-on-write barrier: every block this batch writes must
+                # be uniquely owned before the jitted step sees the tables
+                cow0 = self.pool.cow_copies if tr is not None else 0
+                for slot, nv in enumerate(batch.n_valid):
+                    self.pool.ensure_writable(slot, int(nv))
+                self.pool.flush_copies()
+                if tr is not None and self.pool.cow_copies > cow0:
+                    tr.record("cow_copy", copies=self.pool.cow_copies - cow0)
+                tables = self.pool.block_tables_array()
+            cache_before = self.pool.cache
+            logits, new_cache = self._dispatch(self.params, batch, tables)
+            self.pool.update(new_cache)
+            if self._paged:
+                self.pool.advance(batch.n_valid)
         # fault injection (step surface): corrupt chosen rows' logits on
         # the host, modeling a transient corruption of the step's output;
         # the detector below must catch every one before emission
@@ -432,26 +456,33 @@ class ServingEngine:
                 logits = self._injector.corrupt_logits(self._steps, logits,
                                                        bad_rows)
                 self.metrics.faults_injected += len(bad_rows)
-        pp_batch, q_finished, q_emitted, q_prompt = (
-            self._quarantine(batch, logits, tables) if self._detect
-            else (batch, [], 0, 0))
+        if self._detect:
+            # profiler only: the ring records one quarantine event per row
+            with telemetry.span("quarantine"):
+                pp_batch, q_finished, q_emitted, q_prompt = self._quarantine(
+                    batch, logits, tables)
+        else:
+            pp_batch, q_finished, q_emitted, q_prompt = batch, [], 0, 0
         finished, emitted, prompt_toks = self._postprocess(pp_batch, logits)
         finished += q_finished
         emitted += q_emitted
         prompt_toks += q_prompt
-        if tr is not None:
-            t1 = time.perf_counter()
-            for r, kind in zip(batch.rows, batch.row_kinds):
-                tr.record("prefill_chunk" if kind == "prefill"
-                          else "decode_step", rid=r.rid, t=t0, dur=t1 - t0,
-                          slot=r.slot, n_valid=int(batch.n_valid[r.slot]))
-            for r in finished:
-                tr.record("finished", rid=r.rid, reason=r.finish_reason,
-                          generated=len(r.generated))
-        self.metrics.record_step(
-            batch.kind, self.pool.occupancy, len(self.queue),
-            prompt_tokens=prompt_toks, generated_tokens=emitted,
-            block_stats=self._windowed_block_stats() if self._paged else None)
+        with span("account"):
+            if tr is not None:
+                # a row's span: from its dispatch to its accounting
+                dur = telemetry.clock() - dsp.t
+                for r, kind in zip(batch.rows, batch.row_kinds):
+                    tr.record("prefill_chunk" if kind == "prefill"
+                              else "decode_step", rid=r.rid, t=dsp.t, dur=dur,
+                              slot=r.slot, n_valid=int(batch.n_valid[r.slot]))
+                for r in finished:
+                    tr.record("finished", rid=r.rid, reason=r.finish_reason,
+                              generated=len(r.generated))
+            self.metrics.record_step(
+                batch.kind, self.pool.occupancy, len(self.queue),
+                prompt_tokens=prompt_toks, generated_tokens=emitted,
+                block_stats=(self._windowed_block_stats() if self._paged
+                             else None))
         self._steps += 1
         if (self._probe is not None
                 and self._steps % self.ecfg.error_probe_every == 0):
@@ -476,16 +507,12 @@ class ServingEngine:
             self._finish_count += 1
             if not self._shadow.wants(self._finish_count):
                 continue
-            t0 = time.perf_counter()
-            rec = self._shadow.replay(r.prompt, r.generated)
-            t1 = time.perf_counter()
+            with telemetry.spans(self.tracer)("shadow", rid=r.rid) as sp:
+                rec = self._shadow.replay(r.prompt, r.generated)
+                sp.set(tokens=rec["tokens"], matches=rec["matches"],
+                       logits_err_var=rec["logits_err"]["var"],
+                       logits_err_max_abs=rec["logits_err"]["max_abs"])
             self.metrics.record_shadow(rec)
-            if self.tracer is not None:
-                self.tracer.record(
-                    "shadow", rid=r.rid, t=t0, dur=t1 - t0,
-                    tokens=rec["tokens"], matches=rec["matches"],
-                    logits_err_var=rec["logits_err"]["var"],
-                    logits_err_max_abs=rec["logits_err"]["max_abs"])
 
     def shadow_verdict(self) -> dict | None:
         """The accumulated accuracy-vs-power A/B verdict (None when no
@@ -577,18 +604,18 @@ class ServingEngine:
         """Run the jitted slot step under the given parameter set.
 
         The parameters are a traced argument, so draft and exact packs
-        share one callable and the jit cache keys on
-        (parameter structure, token shape)."""
+        share the callables and each one's jit cache keys on the parameter
+        structure."""
+        fn = self._step_fns[_shape(batch)]
         if self._paged:
-            return self._step_fn(params, jnp.asarray(batch.tokens),
-                                 self.pool.cache, jnp.asarray(batch.n_valid),
-                                 jnp.asarray(tables))
-        return self._step_fn(params, jnp.asarray(batch.tokens),
-                             self.pool.cache, jnp.asarray(batch.n_valid))
+            return fn(params, jnp.asarray(batch.tokens), self.pool.cache,
+                      jnp.asarray(batch.n_valid), jnp.asarray(tables))
+        return fn(params, jnp.asarray(batch.tokens), self.pool.cache,
+                  jnp.asarray(batch.n_valid))
 
     # -- speculative rounds (repro.serving.speculative) ----------------------
 
-    def _speculative_step(self, rnd) -> list[Request]:
+    def _speculative_step(self, rnd, st: telemetry.Span) -> list[Request]:
         """One draft-and-verify round.
 
         Draft: up to ``rnd.max_k`` thin calls with the APPROXIMATE
@@ -603,6 +630,7 @@ class ServingEngine:
         bit-identical to plain exact decode — and the final cursors land
         on exactly the accepted history."""
         tr = self.tracer
+        span = telemetry.spans(tr)
         self.metrics.start_clock()
         ch = self.ecfg.prefill_chunk
         tables = None
@@ -612,30 +640,33 @@ class ServingEngine:
             # land in blocks made uniquely owned here, so the tables stay
             # valid across every dispatch below (rollback is a cursor move
             # — it never frees or remaps a block)
-            cow0 = self.pool.cow_copies if tr is not None else 0
-            for r in rnd.prefilling:
-                self.pool.ensure_writable(
-                    r.slot, min(ch, r.prompt_len - r.prefilled))
-            for row in rnd.spec_rows:
-                self.pool.ensure_writable(row.req.slot, row.k_eff + 1)
-            for r in rnd.plain:
-                self.pool.ensure_writable(r.slot, 1)
-            self.pool.flush_copies()
-            if tr is not None and self.pool.cow_copies > cow0:
-                tr.record("cow_copy", copies=self.pool.cow_copies - cow0)
-            tables = self.pool.block_tables_array()
+            with span("dispatch", call="cow"):
+                cow0 = self.pool.cow_copies if tr is not None else 0
+                for r in rnd.prefilling:
+                    self.pool.ensure_writable(
+                        r.slot, min(ch, r.prompt_len - r.prefilled))
+                for row in rnd.spec_rows:
+                    self.pool.ensure_writable(row.req.slot, row.k_eff + 1)
+                for r in rnd.plain:
+                    self.pool.ensure_writable(r.slot, 1)
+                self.pool.flush_copies()
+                if tr is not None and self.pool.cow_copies > cow0:
+                    tr.record("cow_copy", copies=self.pool.cow_copies - cow0)
+                tables = self.pool.block_tables_array()
         base = self.pool.lengths()
 
         # -- draft phase: thin calls, APPROXIMATE parameters ----------------
-        t_d0 = time.perf_counter()
         max_k = rnd.max_k
+        t_draft = telemetry.clock() if tr is not None else 0.0
         for i in range(max_k):
             db = self.scheduler.draft_batch(rnd, i)
-            logits, new_cache = self._dispatch(self.draft_params, db, tables)
-            self.pool.update(new_cache)
-            toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
+            with span("dispatch", call="draft"):
+                logits, new_cache = self._dispatch(self.draft_params, db,
+                                                   tables)
+                self.pool.update(new_cache)
+            with span("fetch", call="draft"):
+                toks = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
             speculative.record_drafts(rnd, i, toks)
-        t_d1 = time.perf_counter()
         if max_k:
             # the draft K/V above each base cursor is approximate junk:
             # retreat the cursors (repro.models.lm.rollback_slots) and let
@@ -644,11 +675,12 @@ class ServingEngine:
 
         # -- verify phase: ONE chunk-shaped call, EXACT parameters ----------
         vb = self.scheduler.verify_batch(rnd)
-        t_v0 = time.perf_counter()
-        cache_before = self.pool.cache
-        logits, new_cache = self._dispatch(self.params, vb, tables)
-        self.pool.update(new_cache)
-        t_v1 = time.perf_counter()
+        if st.recording:
+            st.set(shape=_shape(vb), draft_calls=max_k, **_row_counts(vb))
+        with span("dispatch", call="verify") as verify:
+            cache_before = self.pool.cache
+            logits, new_cache = self._dispatch(self.params, vb, tables)
+            self.pool.update(new_cache)
 
         (finished, emitted, prompt_toks,
          drafted, accepted) = self._spec_postprocess(rnd, vb, logits)
@@ -667,30 +699,34 @@ class ServingEngine:
             final[row.req.slot] = base[row.req.slot] + row.emitted
         self.pool.set_lengths(final)
 
-        if tr is not None:
-            for r, kind in zip(vb.rows, vb.row_kinds):
-                if kind == "verify":
-                    continue
-                tr.record("prefill_chunk" if kind == "prefill"
-                          else "decode_step", rid=r.rid, t=t_v0,
-                          dur=t_v1 - t_v0, slot=r.slot,
-                          n_valid=int(vb.n_valid[r.slot]))
-            for row in rnd.spec_rows:
-                tr.record("draft", rid=row.req.rid, t=t_d0,
-                          dur=t_d1 - t_d0, slot=row.req.slot, k=row.k_eff)
-                tr.record("verify", rid=row.req.rid, t=t_v0,
-                          dur=t_v1 - t_v0, slot=row.req.slot,
-                          drafted=row.k_eff, accepted=row.accepted,
-                          emitted=row.emitted)
-            for r in finished:
-                tr.record("finished", rid=r.rid, reason=r.finish_reason,
-                          generated=len(r.generated))
-        self.metrics.record_step(
-            "spec" if rnd.spec_rows else ("mixed" if rnd.plain else "prefill"),
-            self.pool.occupancy, len(self.queue),
-            prompt_tokens=prompt_toks, generated_tokens=emitted,
-            block_stats=self._windowed_block_stats() if self._paged else None,
-            drafted=drafted, accepted=accepted, draft_calls=max_k)
+        with span("account"):
+            if tr is not None:
+                for r, kind in zip(vb.rows, vb.row_kinds):
+                    if kind == "verify":
+                        continue
+                    tr.record("prefill_chunk" if kind == "prefill"
+                              else "decode_step", rid=r.rid, t=verify.t,
+                              dur=verify.dur, slot=r.slot,
+                              n_valid=int(vb.n_valid[r.slot]))
+                for row in rnd.spec_rows:
+                    tr.record("draft", rid=row.req.rid, t=t_draft,
+                              dur=verify.t - t_draft, slot=row.req.slot,
+                              k=row.k_eff)
+                    tr.record("verify", rid=row.req.rid, t=verify.t,
+                              dur=verify.dur, slot=row.req.slot,
+                              drafted=row.k_eff, accepted=row.accepted,
+                              emitted=row.emitted)
+                for r in finished:
+                    tr.record("finished", rid=r.rid, reason=r.finish_reason,
+                              generated=len(r.generated))
+            self.metrics.record_step(
+                "spec" if rnd.spec_rows
+                else ("mixed" if rnd.plain else "prefill"),
+                self.pool.occupancy, len(self.queue),
+                prompt_tokens=prompt_toks, generated_tokens=emitted,
+                block_stats=(self._windowed_block_stats() if self._paged
+                             else None),
+                drafted=drafted, accepted=accepted, draft_calls=max_k)
         self._steps += 1
         if (self._probe is not None
                 and self._steps % self.ecfg.error_probe_every == 0):
@@ -714,12 +750,19 @@ class ServingEngine:
         request).  Returns ``(finished, generated_tokens, prompt_tokens,
         drafted, accepted)``; the acceptance counters use the agreement
         length, independent of stop-condition truncation."""
-        finished: list[Request] = []
-        emitted = prompt_toks = drafted = accepted = 0
+        span = telemetry.spans(self.tracer)
         # verify rows consume up to k_eff + 1 columns each, so take the
         # argmax over the full (slots, C, V) block once; every row kind
         # then reads from the same host array
-        toks = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("fetch"):
+            toks = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("emit"):
+            return self._spec_emit(rnd, vb, toks)
+
+    def _spec_emit(self, rnd, vb: ScheduledBatch,
+                   toks) -> tuple[list[Request], int, int, int, int]:
+        finished: list[Request] = []
+        emitted = prompt_toks = drafted = accepted = 0
         for r, kind in zip(vb.rows, vb.row_kinds):
             if kind == "prefill":
                 n = int(vb.n_valid[r.slot])
@@ -764,42 +807,40 @@ class ServingEngine:
         A dense-surface fault injector arms its thread-local hook around
         the probe's observe forward — a degraded MAC array corrupts what
         the probe measures, which is exactly how the governor sees it —
-        and the report feeds the governor's running SLO estimate."""
-        t0 = time.perf_counter()
+        and the report feeds the governor's running SLO estimate.  The
+        ``probe`` span's duration is the eager probe forward's wall time:
+        the decode gap it opens inside the step loop is then attributable
+        to the probe instead of scheduler idle."""
         inj = self._injector
-        if inj is not None and inj.spec.surface == "dense":
-            log0 = len(inj.log)
-            with inj.armed(self._steps):
+        with telemetry.spans(self.tracer)("probe") as sp:
+            if inj is not None and inj.spec.surface == "dense":
+                log0 = len(inj.log)
+                with inj.armed(self._steps):
+                    report = self._probe.run(self.params, batch.tokens,
+                                             batch.n_valid, cache_before,
+                                             block_tables=tables)
+                self.metrics.faults_injected += len(inj.log) - log0
+            else:
                 report = self._probe.run(self.params, batch.tokens,
                                          batch.n_valid, cache_before,
                                          block_tables=tables)
-            self.metrics.faults_injected += len(inj.log) - log0
-        else:
-            report = self._probe.run(self.params, batch.tokens,
-                                     batch.n_valid, cache_before,
-                                     block_tables=tables)
-        t1 = time.perf_counter()
-        if report is None:
-            return
-        rid = next((r.rid for r in batch.rows if r.slot == report["row"]),
-                   None)
+            if report is None:
+                sp.drop()
+                return
+            if sp.recording:
+                lvars = {p: st["var"] for p, st in report["layers"].items()}
+                extra = {}
+                if lvars:
+                    worst = max(lvars, key=lvars.get)
+                    extra = {"max_layer_err_var": lvars[worst],
+                             "worst_layer": worst}
+                sp.set(rid=next((r.rid for r in batch.rows
+                                 if r.slot == report["row"]), None),
+                       logits_err_var=report["logits"]["var"],
+                       logits_err_max_abs=report["logits"]["max_abs"],
+                       mean_layer_err_var=(sum(lvars.values()) / len(lvars)
+                                           if lvars else 0.0), **extra)
         self.metrics.record_probe(report)
-        if self.tracer is not None:
-            # the span's duration is the eager probe forward's wall time:
-            # the decode gap it opens inside the step loop is then
-            # attributable to the probe instead of scheduler idle
-            lvars = {p: st["var"] for p, st in report["layers"].items()}
-            extra = {}
-            if lvars:
-                worst = max(lvars, key=lvars.get)
-                extra = {"max_layer_err_var": lvars[worst],
-                         "worst_layer": worst}
-            self.tracer.record(
-                "probe", rid=rid, t=t0, dur=t1 - t0,
-                logits_err_var=report["logits"]["var"],
-                logits_err_max_abs=report["logits"]["max_abs"],
-                mean_layer_err_var=(sum(lvars.values()) / len(lvars)
-                                    if lvars else 0.0), **extra)
         if self.governor is not None:
             self._apply_decision(self.governor.observe_probe(report))
 
@@ -911,8 +952,8 @@ class ServingEngine:
         return imported
 
     def compile_count(self) -> int:
-        """Number of shapes the jitted slot step has compiled for."""
-        return self._step_fn._cache_size()
+        """Number of shapes the jitted slot steps have compiled for."""
+        return sum(fn._cache_size() for fn in self._step_fns.values())
 
     def reset_metrics(self) -> None:
         """Fresh counters (e.g. after warmup) without losing the numerics
@@ -948,19 +989,27 @@ class ServingEngine:
         ``(finished, generated_tokens, prompt_tokens)`` — per-row
         attribution, so mixed batches account both kinds at once.
         """
-        finished, emitted, prompt_toks = [], 0, 0
         emitting = any(
             kind == "decode"
             or r.prefilled + int(batch.n_valid[r.slot]) >= r.prompt_len
             for r, kind in zip(batch.rows, batch.row_kinds))
+        span = telemetry.spans(self.tracer)
         toks = None
         if emitting:
             # gather each row's one needed column (n_valid-1) BEFORE the
             # argmax, then ship a (slots,) int array — not an argmax over
             # all C columns of (slots, C, V) in the hot serving loop
-            cols = jnp.asarray(np.maximum(batch.n_valid - 1, 0))
-            picked = jnp.take_along_axis(logits, cols[:, None, None], axis=1)
-            toks = np.asarray(jnp.argmax(picked[:, 0], axis=-1))
+            with span("fetch"):
+                cols = jnp.asarray(np.maximum(batch.n_valid - 1, 0))
+                picked = jnp.take_along_axis(logits, cols[:, None, None],
+                                             axis=1)
+                toks = np.asarray(jnp.argmax(picked[:, 0], axis=-1))
+        with span("emit"):
+            return self._emit(batch, toks)
+
+    def _emit(self, batch: ScheduledBatch,
+              toks) -> tuple[list[Request], int, int]:
+        finished, emitted, prompt_toks = [], 0, 0
         for r, kind in zip(batch.rows, batch.row_kinds):
             if kind == "prefill":
                 n = int(batch.n_valid[r.slot])
@@ -1021,3 +1070,17 @@ class ServingEngine:
         del self.active[r.slot]
         self.metrics.record_finish(r)
         return r
+
+
+def _shape(batch: ScheduledBatch) -> str:
+    """Which compiled step a batch runs: ``decode`` (slots, 1) or ``chunk``
+    (slots, prefill_chunk)."""
+    return "decode" if batch.tokens.shape[1] == 1 else "chunk"
+
+
+def _row_counts(batch: ScheduledBatch) -> dict:
+    """Prompt tokens and decode tokens a batch processes (span args)."""
+    nv = batch.n_valid
+    decode = sum(int(nv[r.slot]) for r, kind in
+                 zip(batch.rows, batch.row_kinds) if kind != "prefill")
+    return {"prompt_rows": int(nv.sum()) - decode, "decode_rows": decode}

@@ -20,6 +20,8 @@ import itertools
 import time
 from typing import Callable
 
+from repro.serving import telemetry
+
 
 class RequestState(enum.Enum):
     QUEUED = "queued"
@@ -66,10 +68,11 @@ class Request:
     generated: list[int] = dataclasses.field(default_factory=list)
 
     t_submit: float = dataclasses.field(default_factory=time.time)
-    #: monotonic (perf_counter) submission stamp for span tracing — queue
-    #: waits and step durations must not jump with wall-clock adjustments
+    #: submission stamp on the span clock (``telemetry.clock``, monotonic):
+    #: the queue wait an ``admit`` span carries must not jump with
+    #: wall-clock adjustments
     t_queued_mono: float = dataclasses.field(
-        default_factory=time.perf_counter, repr=False)
+        default_factory=telemetry.clock, repr=False)
     t_first_token: float | None = None
     t_last_token: float | None = None
     t_finish: float | None = None

@@ -34,6 +34,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.serving import telemetry
 from repro.serving.kv_pool import SlotPool
 from repro.serving.request import Request, RequestQueue, RequestState
 
@@ -106,12 +107,11 @@ class SlotScheduler:
         prefill with that much of its prompt already marked done (at least
         one token always remains, to produce its first-token logits).
 
-        ``tracer`` (a :class:`repro.serving.telemetry.SpanTracer`) gets an
-        ``admitted`` span per placement (with the request's queue wait) and
-        a ``capacity_stall`` span per stalled iteration.
+        Each placement opens a zero-length ``admit`` span (with the
+        request's id and queue wait); ``tracer`` (the engine's
+        :class:`repro.serving.telemetry.SpanTracer`, when its ring is on)
+        records it, and a ``capacity_stall`` event per stalled iteration.
         """
-        import time
-
         admitted = []
         stalled = False
         while len(queue):
@@ -129,11 +129,9 @@ class SlotScheduler:
             req.state = RequestState.PREFILL
             active[slot] = req
             admitted.append(req)
-            if tracer is not None:
-                tracer.record(
-                    "admitted", rid=req.rid, slot=slot,
-                    queue_wait_s=round(
-                        time.perf_counter() - req.t_queued_mono, 6))
+            telemetry.instant(
+                tracer, "admit", rid=req.rid, slot=slot,
+                queue_wait_s=round(telemetry.clock() - req.t_queued_mono, 6))
         if stalled:
             if metrics is not None:
                 metrics.no_capacity_stalls += 1

@@ -11,6 +11,7 @@ CV claim, observable from the serving path.
 """
 
 import dataclasses
+import glob
 import json
 import math
 import os
@@ -27,7 +28,8 @@ from repro.launch.serve import ServeConfig, build_serving_params
 from repro.models import build_model
 from repro.serving import EngineMetrics, ServingEngine, SpanTracer
 from repro.serving.metrics import Reservoir, _merge_moments, _percentile
-from repro.serving.telemetry import LIFECYCLE_KINDS
+from repro.serving import telemetry
+from repro.serving.telemetry import LIFECYCLE_KINDS, PHASE_KINDS
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import trace_report  # noqa: E402
@@ -183,7 +185,7 @@ def test_chrome_trace_schema():
 def test_write_and_report_loader_roundtrip(tmp_path):
     tr = SpanTracer(capacity=64, engine="eng0")
     tr.record("queued", rid=0, prompt_len=5)
-    tr.record("admitted", rid=0, slot=1, queue_wait_s=0.001)
+    tr.record("admit", rid=0, slot=1, queue_wait_s=0.001)
     tr.record("prefill_chunk", rid=0, dur=0.002, n_valid=5)
     tr.record("decode_step", rid=0, dur=0.001)
     tr.record("finished", rid=0, reason="length", generated=1)
@@ -242,8 +244,8 @@ def test_traced_engine_lifecycle_spans(model_and_params, tmp_path):
              for k in LIFECYCLE_KINDS}
         if not t["finished"]:
             continue
-        assert t["queued"][0] <= t["admitted"][0]
-        assert t["admitted"][0] <= min(t["prefill_chunk"])
+        assert t["queued"][0] <= t["admit"][0]
+        assert t["admit"][0] <= min(t["prefill_chunk"])
         assert min(t["prefill_chunk"]) <= t["finished"][0]
         if t["decode_step"]:
             assert min(t["prefill_chunk"]) <= min(t["decode_step"])
@@ -283,6 +285,74 @@ def test_trace_report_formats_on_engine_trace(model_and_params, tmp_path,
     rep = json.loads(capsys.readouterr().out)
     assert rep["events"] == len(eng.tracer)
     assert len(rep["requests"]) == 2
+
+
+def test_span_without_ring_records_nothing():
+    tr = SpanTracer()
+    with telemetry.span("fetch", rows=3) as sp:
+        sp.set(waited=1)
+    telemetry.instant(None, "admit", rid=4, queue_wait_s=0.1)
+    assert len(tr) == 0 and sp.dur >= 0.0
+    # the same calls with the ring: one event each, arguments kept
+    with tr.span("fetch", rows=2):
+        pass
+    with tr.span("step", step=7) as st:
+        st.set(shape="decode")
+    telemetry.instant(tr, "admit", rid=4, queue_wait_s=0.1)
+    got = [(e.kind, e.rid, e.data) for e in tr.events()]
+    assert got == [("fetch", None, {"rows": 2}),
+                   ("step", None, {"step_num": 7, "shape": "decode"}),
+                   ("admit", 4, {"queue_wait_s": 0.1})]
+
+
+def _profiled_engine_spans(trace_dir) -> list:
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    pd = ProfileData.from_file(path[-1])
+    return sorted([int(ev.start_ns), int(ev.duration_ns), ev.name,
+                   dict(ev.stats)]
+                  for plane in pd.planes if plane.name.startswith("/host:")
+                  for line in plane.lines for ev in line.events
+                  if ev.name.startswith(telemetry.PREFIX))
+
+
+def test_engine_phases_on_the_profilers_clock(model_and_params, tmp_path):
+    cfg, _, params = model_and_params
+    eng = ServingEngine(cfg, params,
+                        EngineConfig(slots=2, max_len=64, prefill_chunk=16,
+                                     cache_dtype="float32", trace=True))
+    reqs = _requests(cfg.vocab, n=3)
+    eng.submit(*reqs[0])
+    eng.run()  # both shapes compile outside the trace
+    eng.tracer.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    for p, g in reqs[1:]:
+        eng.submit(p, g)
+    eng.run()
+    jax.profiler.stop_trace()
+    spans = _profiled_engine_spans(tmp_path)
+    steps = [sp for sp in spans if sp[2] == "engine.step"]
+    assert steps and all("shape" in sp[3] for sp in steps)
+    for s, d, _, args in steps:
+        kids = {n for a, b, n, _ in spans if s <= a and a + b <= s + d}
+        assert {"engine.schedule", "engine.dispatch", "engine.emit",
+                "engine.account"} <= kids
+        assert args["shape"] in ("decode", "chunk")
+    assert {sp[3]["step_num"] for sp in steps} == set(range(
+        steps[0][3]["step_num"], steps[0][3]["step_num"] + len(steps)))
+    assert sum(sp[2] == "engine.fetch" for sp in spans) >= len(steps) - 2
+    assert sum(sp[2] == "engine.admit" for sp in spans) == 2
+    # the anchor puts the ring's records of the same phases on the trace
+    offset = steps[0][0] - steps[0][3]["clock_ns"]
+    for kind in PHASE_KINDS:
+        mine = sorted((e.t, e.dur) for e in eng.tracer.events()
+                      if e.kind == kind)
+        theirs = [sp for sp in spans if sp[2] == telemetry.PREFIX + kind]
+        assert len(mine) == len(theirs), kind
+        for (t, dur), (s, d, _, _) in zip(mine, theirs):
+            assert abs(t * 1e9 + offset - s) < 1e6
+            assert abs(dur * 1e9 - d) < 1e6
 
 
 def _probe_logits_var(cfg, params, policy):

@@ -41,7 +41,7 @@ acceptance, prefix imports, and capacity-stall attribution.
 Single-trace invocations are unchanged.
 
 ``--assert-lifecycle`` exits non-zero unless the trace holds at least one
-span of every request-lifecycle stage (queued, admitted, prefill_chunk,
+span of every request-lifecycle stage (queued, admit, prefill_chunk,
 decode_step, finished) — the CI smoke's trace-integrity gate.
 ``--assert-quarantine`` exits non-zero unless every ``fault_detected``
 span is matched by a ``quarantine`` span (the fault-injection smoke's
@@ -55,7 +55,7 @@ import collections
 import json
 import sys
 
-LIFECYCLE = ("queued", "admitted", "prefill_chunk", "decode_step", "finished")
+LIFECYCLE = ("queued", "admit", "prefill_chunk", "decode_step", "finished")
 
 
 def load_events(path: str) -> list[dict]:
@@ -131,7 +131,7 @@ def _request_timelines(events: list[dict]) -> dict:
         k = e["kind"]
         if k == "queued":
             r["queued_t"] = e["t"]
-        elif k == "admitted":
+        elif k in ("admit", "admitted"):  # "admitted": older traces
             r["queue_wait_s"] = e["data"].get("queue_wait_s")
         elif k == "prefill_chunk":
             r["prefill_chunks"] += 1
