@@ -23,8 +23,10 @@ dry-run lower/compile):
   * remat policy per config ("none" | "full" | "dots") wraps the scanned
     block body.
 
-Caches are plain pytrees stacked over layers, so `lax.scan` slices them per
-layer during decode and pjit shards them like any other state.
+Caches are plain pytrees stacked over layers: `lax.scan` slices them per
+layer in `decode_step`, carries them through the served `decode_slots`
+(which writes each step's columns in place), and pjit shards them like
+any other state.
 """
 
 from __future__ import annotations
@@ -34,6 +36,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.configs.base import ArchConfig
 from repro.core.approx_linear import dense
@@ -631,7 +634,7 @@ def rollback_slots(cache: dict, new_lengths) -> dict:
 
     Moving ``lengths`` back is a complete rollback for every cache layout
     this module builds: the attention mask hides entries at positions
-    ``>= lengths[b]``, and ``_slot_update`` writes a slot's next tokens
+    ``>= lengths[b]``, and the cache write puts a slot's next tokens
     over those positions BEFORE attention reads the cache — so stale
     K/V from rejected speculative tokens is never attended and is
     overwritten before it can be.  Works identically for the contiguous
@@ -667,46 +670,101 @@ def _paged_scatter(pool: jax.Array, bt: jax.Array, view: jax.Array) -> jax.Array
     return pool.at[bt].set(jnp.moveaxis(blocks, -3, 1))
 
 
-def _slot_update(cache_arr: jax.Array, update: jax.Array, starts: jax.Array,
-                 n_valid: jax.Array):
-    """Per-row write: row b's first ``n_valid[b]`` update columns land at
-    [starts[b], starts[b]+n_valid[b]) on the -2 axis of row b.
+def _write_plan(u: jax.Array, st, nv, s: int):
+    """One row's cache write: row's first ``nv`` columns of the update
+    block ``u`` (seq axis -2, C columns) land at [st, st + nv) of a stripe
+    of length ``s``.  Returns the block to write, the mask of the columns
+    that really write (the others keep the OLD cache values), and the start
+    the block is written at.
 
-    Padding columns (>= n_valid[b]) are blended back to the OLD cache
-    values, so they never write.  This matters beyond hygiene:
-    ``dynamic_update_slice`` CLAMPS out-of-range starts.  A padding row
-    (n_valid == 0) whose cursor exceeds S - C would otherwise have its
+    Padding columns (>= nv) must never write.  This matters beyond
+    hygiene: ``dynamic_update_slice`` CLAMPS out-of-range starts.  A padding
+    row (n_valid == 0) whose cursor exceeds S - C would otherwise have its
     block write clamped back over valid, attended entries — and a MIXED
     batch legitimately carries short rows deep in their stripe (a decode
     row with n_valid == 1 riding a chunk-shaped call can sit anywhere up
     to S - 1).  The write is therefore clamp-aware: the update block is
     rolled by the clamp displacement so its valid head still lands at
-    [starts, starts + n_valid), and the blend mask is expressed in the
-    clamped coordinates.  For rows that do not clamp this reduces to the
-    plain masked blend."""
-    c_len = update.shape[-2]
+    [st, st + nv), and the mask is expressed in the clamped coordinates.
+    For rows that do not clamp this reduces to the plain masked blend."""
+    c_len = u.shape[-2]
+    if c_len > 1:
+        # where dynamic_update_slice will actually place the block
+        st_eff = jnp.clip(st, 0, max(s - c_len, 0))
+        shift = st - st_eff  # > 0 only when the raw start would clamp
+        u = jnp.roll(u, shift, axis=-2)  # u[0] realigns to cache col st
+        idx = jnp.arange(c_len)
+        mask = (idx >= shift) & (idx < shift + nv)
+        st = st_eff
+    else:
+        # static fast path: a one-column write can never clamp (every
+        # cursor is <= S - 1), so skip the dynamic roll on the thin
+        # (slots, 1) decode step — the hottest per-layer write
+        mask = jnp.arange(c_len) < nv
+    return u, mask.reshape((1,) * (u.ndim - 2) + (c_len, 1)), st
+
+
+def _slot_update(cache_arr: jax.Array, update: jax.Array, starts: jax.Array,
+                 n_valid: jax.Array):
+    """Per-row write into one layer's stripes (B, ..., S, d): row b's first
+    ``n_valid[b]`` update columns land at [starts[b], starts[b]+n_valid[b])
+    on the -2 axis of row b (:func:`_write_plan`); returns the new stripes."""
 
     def write(c, u, st, nv):
-        s = c.shape[-2]
-        if c_len > 1:
-            # where dynamic_update_slice will actually place the block
-            st_eff = jnp.clip(st, 0, max(s - c_len, 0))
-            shift = st - st_eff  # > 0 only when the raw start would clamp
-            u = jnp.roll(u, shift, axis=-2)  # u[0] realigns to cache col st
-            idx = jnp.arange(c_len)
-            mask = (idx >= shift) & (idx < shift + nv)
-            st = st_eff
-        else:
-            # static fast path: a one-column write can never clamp (every
-            # cursor is <= S - 1), so skip the dynamic roll on the thin
-            # (slots, 1) decode step — the hottest per-layer write
-            mask = jnp.arange(c_len) < nv
+        u, mask, st = _write_plan(u, st, nv, c.shape[-2])
         start = (0,) * (c.ndim - 2) + (st, 0)
         old = jax.lax.dynamic_slice(c, start, u.shape)
-        mask = mask.reshape((1,) * (u.ndim - 2) + (c_len, 1))
         return jax.lax.dynamic_update_slice(c, jnp.where(mask, u, old), start)
 
     return jax.vmap(write)(cache_arr, update, starts, n_valid)
+
+
+def _slot_write(buf: jax.Array, layer, update: jax.Array, starts: jax.Array,
+                n_valid: jax.Array) -> jax.Array:
+    """In-place form of :func:`_slot_update` on a layer-stacked buffer
+    (L, B, ..., S, d): row b's columns land at [layer, b, ..., st, :].
+
+    One ``dynamic_update_slice`` per slot, of the C columns alone, and only
+    those C old columns are read for the blend, all before the first
+    write (a ``vmap`` over the slots would lower to a scatter).  On a
+    buffer the step donates, the updates stay in place."""
+    rows = []
+    for b in range(update.shape[0]):
+        u, mask, st = _write_plan(update[b], starts[b], n_valid[b],
+                                  buf.shape[-2])
+        start = (layer, b) + (0,) * (u.ndim - 2) + (st, 0)
+        old = jax.lax.dynamic_slice(buf, start, (1, 1) + u.shape)
+        rows.append((start, jnp.where(mask, u[None, None], old)))
+    for start, block in rows:
+        buf = jax.lax.dynamic_update_slice(buf, block, start)
+    return buf
+
+
+def _write_kv(lc: dict, new: dict, lengths, n_valid, layer) -> tuple[dict, dict]:
+    """Write each slot's new cache columns ``new`` into the leaves ``lc``;
+    returns (the stripes attention reads, the updated leaves).
+
+    ``layer`` None: ``lc`` holds one layer's stripes and the updated
+    stripes are both.  Otherwise ``lc`` holds the layer-stacked buffers:
+    the columns land in place at ``layer``, then attention reads that
+    layer's stripe from the updated buffer.  The read sits outside every
+    inner scope, so a copy XLA makes of the stripe counts under
+    ``layer_stack``."""
+    with jax.named_scope("kv_write"):
+        if layer is None:
+            out = {k: _slot_update(lc[k], u, lengths, n_valid)
+                   for k, u in new.items()}
+            return out, out
+        # the buffers keep their row-major layout through the scan: left
+        # to itself, the v5e compiler lays a decode-shaped step's carried
+        # cache out with the positions outside the heads, and copies the
+        # whole stack into and out of the loop
+        out = {k: with_layout_constraint(
+                   _slot_write(lc[k], layer, u, lengths, n_valid),
+                   Layout(tuple(range(lc[k].ndim))))
+               for k, u in new.items()}
+    return ({k: jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
+             for k, v in out.items()}, out)
 
 
 #: Fewest query columns the slot attention's score and context products
@@ -732,8 +790,10 @@ def _pad_queries(q: jax.Array, positions: jax.Array):
             jnp.pad(positions, ((0, 0), (0, pad)), mode="edge"))
 
 
-def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
-    """Multi-token slot attention.  h: (B, C, D); lc k/v: (B, Hkv, S, hd);
+def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions,
+               layer=None):
+    """Multi-token slot attention.  h: (B, C, D); lc k/v: (B, Hkv, S, hd),
+    or (L, B, Hkv, S, hd) written at ``layer`` (:func:`_write_kv`);
     positions: (B, C) absolute positions lengths[b] + i."""
     from repro.nn.attention import _from_cache, _to_cache
 
@@ -743,12 +803,10 @@ def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
         q, k, v = attn_lib._project_qkv(bp, h, acfg,
                                         attn_lib._angles(acfg, positions))
     with jax.named_scope("kv_write"):
-        k_c = _slot_update(lc["k"],
-                           _to_cache(jnp.moveaxis(k, 1, 2), lc["k"].dtype),
-                           lengths, n_valid)
-        v_c = _slot_update(lc["v"],
-                           _to_cache(jnp.moveaxis(v, 1, 2), lc["v"].dtype),
-                           lengths, n_valid)
+        new = {"k": _to_cache(jnp.moveaxis(k, 1, 2), lc["k"].dtype),
+               "v": _to_cache(jnp.moveaxis(v, 1, 2), lc["v"].dtype)}
+    kv, out = _write_kv(lc, new, lengths, n_valid, layer)
+    k_c, v_c = kv["k"], kv["v"]
     hq, hkv, d = acfg.n_heads, acfg.kv_heads, acfg.head_dim
     g = hq // hkv
     with jax.named_scope("attn"):
@@ -764,22 +822,23 @@ def _gqa_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
         ctx = jnp.einsum("bhgqk,bhkd->bqhgd", probs, _from_cache(v_c, q.dtype))
     with jax.named_scope("o_proj"):
         y = dense(bp["o"], ctx[:, :c].reshape(b, c, acfg.q_dim), name="o")
-    return y, {"k": k_c, "v": v_c}
+    return y, out
 
 
-def _mla_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
-    """Weight-absorbed MLA slot attention over the pooled latent cache."""
+def _mla_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions,
+               layer=None):
+    """Weight-absorbed MLA slot attention over the pooled latent cache
+    (one layer's, or the layer-stacked one written at ``layer``)."""
     mcfg = cfg.mla_config()
     b, c, _ = h.shape
     with jax.named_scope("qkv"):
         q_nope, q_rope = attn_lib._mla_q(bp, h, mcfg, positions)
         latent_t, k_rope_t = attn_lib._mla_latent(bp, h, mcfg, positions)
     with jax.named_scope("kv_write"):
-        lat_c = _slot_update(lc["latent"],
-                             latent_t.astype(lc["latent"].dtype),
-                             lengths, n_valid)
-        rope_c = _slot_update(lc["rope"], k_rope_t.astype(lc["rope"].dtype),
-                              lengths, n_valid)
+        new = {"latent": latent_t.astype(lc["latent"].dtype),
+               "rope": k_rope_t.astype(lc["rope"].dtype)}
+    kv, out = _write_kv(lc, new, lengths, n_valid, layer)
+    lat_c, rope_c = kv["latent"], kv["rope"]
 
     with jax.named_scope("attn"):
         w_b = bp["kv_b"]["w"].reshape(
@@ -801,14 +860,16 @@ def _mla_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions):
         ctx = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_uv)
     with jax.named_scope("o_proj"):
         y = dense(bp["o"], ctx.reshape(b, c, -1), name="o")
-    return y, {"latent": lat_c, "rope": rope_c}
+    return y, out
 
 
 #: Named scopes of the served step (``jax.named_scope``): every operation of
 #: ``decode_slots`` falls under one of them, so a device trace, through
 #: each op's ``op_name``, says which layer of the step it belongs to.  An
 #: op under ``layer_stack`` and no inner scope is the layer scan's own
-#: slicing and restacking of the stacked KV cache and weights; ``cv`` (the
+#: slicing of the stacked weights and its read of each layer's cache stripe
+#: (on the paged layout, its slicing and restacking of the stripes);
+#: ``kv_write`` holds the cache writes; ``cv`` (the
 #: control-variate correction, :func:`repro.quant.quantize.quantized_linear`)
 #: nests under the dense scope that calls it.
 STEP_SCOPES = ("embed", "attn_norm", "qkv", "kv_write", "attn", "o_proj",
@@ -817,7 +878,8 @@ STEP_SCOPES = ("embed", "attn_norm", "qkv", "kv_write", "attn", "o_proj",
 
 
 def _block_decode_slots(bp: dict, x, lc: dict, lengths, n_valid,
-                        cfg: ArchConfig, positions, mesh, block_tables=None):
+                        cfg: ArchConfig, positions, mesh, block_tables=None,
+                        layer=None):
     with jax.named_scope("attn_norm"):
         h = apply_norm(cfg.norm, bp["attn_norm"], x)
     pool_lc = None
@@ -827,10 +889,9 @@ def _block_decode_slots(bp: dict, x, lc: dict, lengths, n_valid,
         pool_lc = lc
         with jax.named_scope("kv_write"):
             lc = {k: _paged_gather(v, block_tables) for k, v in lc.items()}
-    if cfg.attn == "mla":
-        a, new = _mla_slots(bp["attn"], h, lc, lengths, n_valid, cfg, positions)
-    else:
-        a, new = _gqa_slots(bp["attn"], h, lc, lengths, n_valid, cfg, positions)
+    slots_attn = _mla_slots if cfg.attn == "mla" else _gqa_slots
+    a, new = slots_attn(bp["attn"], h, lc, lengths, n_valid, cfg, positions,
+                        layer)
     if pool_lc is not None:
         with jax.named_scope("kv_write"):
             new = {k: _paged_scatter(pool_lc[k], block_tables, v)
@@ -879,7 +940,7 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
     exact K/V.  Rollback between and after the phases is
     :func:`rollback_slots` — a pure cursor move, sound because writes land
     before attention and positions past the cursor are masked.  The C == 1
-    fast path in ``_slot_update`` asserts no clamping, so draft cursors
+    fast path in ``_write_plan`` asserts no clamping, so draft cursors
     must stay ``<= max_len - 1``; the serving layer guarantees it by
     capping k at the request's remaining generation budget minus one.
 
@@ -888,6 +949,14 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
     slots <= repro.kernels.ops.DECODE_M_MAX) runs thin-M single-K-step
     launches while prefill chunks (C == prefill_chunk) keep prefill tiles —
     both from the same jitted step, one compiled shape each.
+
+    The contiguous layout's scan carries the layer-stacked cache: each
+    layer writes its slots' new columns into it in place
+    (:func:`_slot_write`) and attention reads the layer's stripe back, so
+    a step whose cache argument is donated moves no more of the cache than
+    it writes and attends.  The paged layout and ``unroll_layers`` take
+    each layer's stripes in and give them back (:func:`_slot_update`),
+    with the same numbers.
 
     ``unroll_layers`` replaces the layer ``lax.scan`` with a python loop
     (per-layer ``observers.scope``d) — ``lax.scan`` traces its body even
@@ -909,24 +978,33 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
         positions = lengths[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
         x = embed(params["embed"], tokens).astype(cdt)
     new_cache = dict(cache)
+    # the contiguous jitted step updates the stacked cache in place (one
+    # write per slot of its new columns, into the buffer the engine
+    # donates); the paged layout and the unrolled probe forward update
+    # per-layer stripes
+    in_place = block_tables is None and not unroll_layers
 
     dense_keys = ("latent", "rope") if cfg.attn == "mla" else ("k", "v")
     for i, bp in enumerate(params.get("dense_blocks", [])):
-        with jax.named_scope("kv_write"):
-            lc = {k: cache[f"dense_{k}"][i] for k in dense_keys}
+        stacked = {k: new_cache[f"dense_{k}"] for k in dense_keys}
+        if in_place:
+            lc, layer = stacked, i
+        else:
+            with jax.named_scope("kv_write"):
+                lc, layer = {k: v[i] for k, v in stacked.items()}, None
         with observers.scope("dense_blocks", i):
             x, new = _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
-                                         positions, mesh, block_tables)
+                                         positions, mesh, block_tables, layer)
         with jax.named_scope("kv_write"):
             for k in dense_keys:
                 new_cache[f"dense_{k}"] = (
-                    new_cache[f"dense_{k}"].at[i].set(new[k]))
+                    new[k] if in_place else stacked[k].at[i].set(new[k]))
 
     layer_keys = [k for k in ("latent", "rope", "k", "v") if k in cache]
     lcs = {k: cache[k] for k in layer_keys}
+    n_layers = lcs[layer_keys[0]].shape[0]
 
     if unroll_layers:
-        n_layers = jax.tree_util.tree_leaves(params["blocks"])[0].shape[0]
         acc: dict[str, list] = {k: [] for k in layer_keys}
         for i in range(n_layers):
             bp = jax.tree.map(lambda a: a[i], params["blocks"])
@@ -938,6 +1016,19 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
             for k in layer_keys:
                 acc[k].append(new[k])
         new_layers = {k: jnp.stack(acc[k]) for k in layer_keys}
+    elif in_place:
+        # the stacked cache rides the carry: each layer writes its columns
+        # into it and reads its own stripe back, nothing is restacked
+        def body(carry, inp):
+            x, kv = carry
+            bp, i = inp
+            return _block_decode_slots(bp, x, kv, lengths, n_valid, cfg,
+                                       positions, mesh, layer=i), None
+
+        with jax.named_scope("layer_stack"):
+            (x, new_layers), _ = jax.lax.scan(
+                body, (x, lcs),
+                (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
     else:
         def body(x, inp):
             bp, lc = inp

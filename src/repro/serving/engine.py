@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig, EngineConfig
 from repro.models import ModelApi, build_model
+from repro.models.lm import rollback_slots
 from repro.serving.kv_pool import SlotPool
 from repro.serving.metrics import EngineMetrics
 from repro.serving.request import (AdmissionController, Request, RequestQueue,
@@ -294,6 +295,15 @@ class ServingEngine:
         self.active: dict[int, Request] = {}
         self._rid = itertools.count()
         decode_slots = self.api.decode_slots
+        # the step donates the pooled cache, so its output cache takes the
+        # input's buffers and the layer scan writes a step's new columns in
+        # place — unless the engine holds the pre-step cache across the
+        # step: the error probe re-runs a row from it.  The shadow runner
+        # replays through a private pool and a jit of its own.
+        self._donate = self._probe is None
+        #: whether a step updates the cache in place: donated, and on the
+        #: contiguous layout (the paged step gathers and scatters blocks)
+        self.kv_in_place = self._donate and not self._paged
 
         def step_fn(name: str):
             if self._paged:
@@ -305,7 +315,8 @@ class ServingEngine:
                     return decode_slots(p, t, c, nv, mesh=mesh)
 
             fn.__name__ = fn.__qualname__ = name
-            return jax.jit(fn, compiler_options=STEP_COMPILER_OPTIONS)
+            return jax.jit(fn, compiler_options=STEP_COMPILER_OPTIONS,
+                           donate_argnums=(2,) if self._donate else ())
 
         # one jitted callable per shape, each compiled once: (slots, 1) and
         # (slots, chunk), so a device trace names the step that ran
@@ -426,7 +437,7 @@ class ServingEngine:
         # construction and the first served batch stays excluded, but the
         # first measured step's own wall time is inside the window
         self.metrics.start_clock()
-        with span("dispatch") as dsp:
+        with span("dispatch", in_place=self.kv_in_place) as dsp:
             tables = None
             if self._paged:
                 # copy-on-write barrier: every block this batch writes must
@@ -607,11 +618,21 @@ class ServingEngine:
         share the callables and each one's jit cache keys on the parameter
         structure."""
         fn = self._step_fns[_shape(batch)]
+        n_valid = jnp.asarray(batch.n_valid)
+        args = (params, jnp.asarray(batch.tokens), self.pool.cache, n_valid)
         if self._paged:
-            return fn(params, jnp.asarray(batch.tokens), self.pool.cache,
-                      jnp.asarray(batch.n_valid), jnp.asarray(tables))
-        return fn(params, jnp.asarray(batch.tokens), self.pool.cache,
-                  jnp.asarray(batch.n_valid))
+            args += (jnp.asarray(tables),)
+        logits, cache = fn(*args)
+        if self._donate:
+            # the step consumed the pool's buffers.  Until the caller stores
+            # the result, the pool holds it at the pre-step cursors: for
+            # position-indexed K/V that is the pre-step cache, as nothing
+            # past a cursor is attended before it is written (see
+            # repro.models.lm.rollback_slots)
+            self.pool.update(rollback_slots(cache, cache["lengths"] - n_valid))
+            if self.kv_in_place:
+                self.metrics.kv_in_place_steps += 1
+        return logits, cache
 
     # -- speculative rounds (repro.serving.speculative) ----------------------
 
@@ -660,7 +681,8 @@ class ServingEngine:
         t_draft = telemetry.clock() if tr is not None else 0.0
         for i in range(max_k):
             db = self.scheduler.draft_batch(rnd, i)
-            with span("dispatch", call="draft"):
+            with span("dispatch", call="draft",
+                      in_place=self.kv_in_place):
                 logits, new_cache = self._dispatch(self.draft_params, db,
                                                    tables)
                 self.pool.update(new_cache)
@@ -677,7 +699,8 @@ class ServingEngine:
         vb = self.scheduler.verify_batch(rnd)
         if st.recording:
             st.set(shape=_shape(vb), draft_calls=max_k, **_row_counts(vb))
-        with span("dispatch", call="verify") as verify:
+        with span("dispatch", call="verify",
+                  in_place=self.kv_in_place) as verify:
             cache_before = self.pool.cache
             logits, new_cache = self._dispatch(self.params, vb, tables)
             self.pool.update(new_cache)

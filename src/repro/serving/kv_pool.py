@@ -97,8 +97,8 @@ class SlotPool:
 
         A cursor move is a sound rollback for attention-style caches:
         entries beyond a slot's cursor are never attended (the position
-        mask) and are overwritten before they are read (``_slot_update``
-        writes before attention), so rejected speculative K/V needs no
+        mask) and are overwritten before they are read (the cache write
+        lands before attention), so rejected speculative K/V needs no
         erasing — only the cursor retreats.  Recurrent (RWKV) state has no
         cursor to move, which is why the engine refuses speculation there.
         """

@@ -207,6 +207,10 @@ class EngineMetrics:
     prefill_steps: int = 0
     decode_steps: int = 0
     mixed_steps: int = 0  # chunk-shaped batches carrying decode rows
+    #: calls of the step program that updated the pooled KV cache in place
+    #: (the donated step on the contiguous layout; draft, verify and
+    #: quarantine-replay calls each count)
+    kv_in_place_steps: int = 0
 
     #: speculative decode config mirror: the engine's draft length
     #: (0 = speculation off) and the NumericsSpec name its draft
@@ -683,6 +687,7 @@ class EngineMetrics:
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
             "mixed_steps": self.mixed_steps,
+            "kv_in_place_steps": self.kv_in_place_steps,
             "step_samples": self._samples,
             "speculative_k": self.speculative_k or None,
             "draft_numerics": self.draft_numerics,
@@ -741,7 +746,8 @@ class EngineMetrics:
         "requests_deadline_expired", "prefix_hits",
         "prefix_hit_tokens", "prefix_imports",
         "prompt_tokens", "generated_tokens",
-        "prefill_steps", "decode_steps", "mixed_steps", "step_samples",
+        "prefill_steps", "decode_steps", "mixed_steps", "kv_in_place_steps",
+        "step_samples",
         "spec_rounds", "draft_calls", "drafted_tokens",
         "accepted_draft_tokens",
         "block_step_samples", "ttft_samples", "ttft_samples_capped",

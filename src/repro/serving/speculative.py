@@ -22,7 +22,7 @@ State before a round: the request has emitted ``g`` tokens, the last one
    the remaining generation allowance.  The ``budget - 1`` cap guarantees
    the round's emissions (``<= k_eff + 1``) never exceed the budget and
    that every cursor the draft phase writes stays ``<= max_len - 1`` (the
-   thin-call fast path in ``_slot_update`` cannot clamp) and inside the
+   thin-call fast path in ``_write_plan`` cannot clamp) and inside the
    paged layout's up-front block reservation.  Slots with ``k_eff == 0``
    (one token of budget left) ride the verify call as plain ``n_valid = 1``
    decode rows instead.

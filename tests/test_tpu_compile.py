@@ -1,6 +1,7 @@
 """Both CV kernels compile for a described TPU v5e at olmo-1b's published
-widths, with no chip attached (the TPU compiler is installed, the chip is
-not needed to compile).
+widths, and the engine's step updates its KV cache in place there, with no
+chip attached (the TPU compiler is installed, the chip is not needed to
+compile).
 
 Shapes are the packs the engine serves: prefill-shaped calls (4 slots x a
 32-token chunk = 128 rows) run the fan-out-fused Q|K|V and gate|up packs,
@@ -10,11 +11,16 @@ topology is described inside a fixture, so collecting this file never
 loads the TPU library.
 """
 
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
+from repro.configs.base import EngineConfig
 from repro.kernels import ops
 from repro.quant.quantize import (EPI_ROWS, META_LEN, BlockedPack,
                                   serving_blocks)
@@ -89,3 +95,73 @@ def test_plain_kernel_compiles_for_v5e(one_chip, case, numerics):
                           vec(jnp.float32), vec(jnp.float32),
                           vec(jnp.int32), vec(jnp.float32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the engine's step updates the stacked KV cache in place
+# ---------------------------------------------------------------------------
+
+#: ops that only name a buffer (or loop over one) and move no data
+_PASS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _unfused_ops(hlo: str):
+    """(opcode, result shape) of each op outside the fused computations."""
+    bodies, fused, cur = {}, set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            cur = head.group(1)
+            bodies[cur] = []
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            bodies[cur].append(line)
+            called = re.search(r" fusion\(.*calls=%([\w.\-]+)", line)
+            if called:
+                fused.add(called.group(1))
+    for name, lines in bodies.items():
+        if name in fused:
+            continue
+        for line in lines:
+            op = re.match(r"^\s*(?:ROOT )?%[\w.\-]+ = \(?\w+\[([\d,]*)\]"
+                          r"(?:\{[^}]*\})? ([\w\-]+)\(", line)
+            if op:
+                yield op.group(2), tuple(int(d) for d in op.group(1).split(",")
+                                         if d)
+
+
+@pytest.mark.parametrize("shape", ["decode", "chunk"])
+def test_engine_step_updates_the_cache_in_place_on_v5e(one_chip, shape):
+    """Both step programs alias the donated cache, and outside the fused
+    computations no op has the stacked cache's or a layer stripe's shape
+    but the per-slot column writes into the stack: the layer scan neither
+    slices, copies nor restacks the cache, and attention reads each
+    stripe inside its own fusion.  At head_dim 128 a decode-shaped step
+    whose carried cache were free to change layout copies the stack."""
+    from repro.models import build_model
+    from repro.serving import ServingEngine
+
+    cfg = dataclasses.replace(get_config("olmo-1b"), n_layers=2, d_model=256,
+                              n_heads=2, kv_heads=2, head_dim=128, d_ff=512,
+                              vocab=512)
+    api = build_model(cfg)
+    params = api.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params, EngineConfig(slots=4, max_len=256,
+                                                  prefill_chunk=16), api=api)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    c = 1 if shape == "decode" else 16
+    hlo = eng._step_fns[shape].lower(
+        on_chip(params), on_chip(jnp.zeros((4, c), jnp.int32)),
+        on_chip(eng.pool.cache), on_chip(jnp.zeros((4,), jnp.int32))
+    ).compile().as_text()
+    head = hlo.splitlines()[0]
+    assert head.startswith(f"HloModule jit_engine_{shape}_step")
+    assert len(re.findall(r"may-alias", head)) == len(eng.pool.cache)
+    stack = eng.pool.cache["k"].shape
+    moved = [(op, dims) for op, dims in _unfused_ops(hlo)
+             if dims[-3:] == stack[-3:] and op not in _PASS
+             and not (op == "dynamic-update-slice" and dims == stack)]
+    assert not moved, moved
