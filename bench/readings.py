@@ -23,14 +23,14 @@ def step_ms(run, kind: str) -> float | None:
 
 
 def dense_roofline(run) -> float | None:
-    """The packed dense layers' least time by the roofline (counts.py, on
-    the rows each traced step computes) over the device time of the dense
-    products in those steps, %."""
+    """The packed dense layers' least time by the roofline (the family's
+    ``dense_work`` on the rows each traced step computes) over the device
+    time of the dense products in those steps, %."""
     steps = run.traced_steps()
     if not steps:
         return None
     least = sum(counts.least_time(
-        *counts.dense_work(run.model, run.rows(s), run.cv),
+        *run.family.dense_work(run.model, run.rows(s), run.cv),
         run.peaks["int8_ops_per_s"], run.peaks["hbm_bytes_per_s"])[0]
         for s, _, _ in steps)
     dense_s = trace_lib.dense_ns(run.trace, [sp for _, sp, _ in steps]) / 1e9
@@ -38,13 +38,15 @@ def dense_roofline(run) -> float | None:
 
 
 def step_mfu(run) -> float | None:
-    """Nominal model operations of the traced steps over their step
-    programs' device time times the peak, %."""
+    """Nominal model operations of the traced steps (the family's
+    ``nominal_ops``) over their step programs' device time times the peak,
+    %."""
     steps = [(s, n) for s, _, n in run.traced_steps() if n > 0]
     if not steps:
         return None
-    ops = sum(counts.nominal_ops(run.model, s.prompt_rows + s.decode_rows,
-                                 s.context_sum) for s, _ in steps)
+    ops = sum(run.family.nominal_ops(
+        run.model, s.prompt_rows + s.decode_rows, s.context_sum)
+        for s, _ in steps)
     dev_s = sum(n for _, n in steps) / 1e9
     return 100.0 * ops / (dev_s * peak_ops(run))
 

@@ -4,18 +4,20 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 A cell (``workloads/<cell>.json``) names a configuration
-(``configs/<config>.json``: the model, the numerics pack it is served in,
-the engine's settings), a traffic mix (``traffic/<mix>.json``), the chips,
-and the fixed rate (open loop) or number of clients (closed loop).  The
-run:
+(``configs/<config>.json``: the model and its family, the numerics pack it
+is served in, the engine's settings), a traffic mix
+(``traffic/<mix>.json``), the chips, and the fixed rate (open loop) or
+number of clients (closed loop).  The run:
 
 1. checks the devices: a TPU whose ``device_kind`` is in ``peaks.json``,
    as many chips as the cell asks for; otherwise it exits non-zero and
    prints no result;
-2. sets up: seeded weights made on the device, packed by the program's
-   ``build_serving_params``, the float weights freed, the serving engine
-   built (its KV cache allocated), its two step shapes and its token pick
-   warmed up on a few requests;
+2. sets up: seeded weights made on the device by the configuration's
+   family (``families/<family>.py``) and checked against the program's
+   parameter tree, packed by the program's ``build_serving_params``, the
+   float weights freed, the serving engine built (its KV cache
+   allocated), its two step shapes and its token pick warmed up on a few
+   requests;
 3. drives ``ServingEngine.step()`` for ``--seconds``, feeding requests
    through ``submit`` as the mix says, and records on the host clock when
    each was due, submitted, given a slot, and each of its tokens; an open
@@ -25,8 +27,8 @@ run:
    ``TRACE_S`` seconds and reports the cell's per-layer metrics
    (``metrics/<metric>.py``) in place of its end-to-end ones;
 5. checks what the window served: the engine state is freed, the weights
-   are made again from the seed, and the plain float32 reference
-   (``reference.py``) runs over a seeded sample of the finished requests,
+   are made again from the seed, and the family's plain float32
+   reference runs over a seeded sample of the finished requests,
    teacher-forced on the tokens they were served, reading at each served
    position the gap by which the served token's reference logit lies
    below the reference's best.  The numbers compared (the cell's
@@ -60,10 +62,12 @@ import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 CHECKOUT = BENCH.parent
@@ -73,14 +77,11 @@ for _p in (CHECKOUT / "src", CHECKOUT):
 
 import numpy as np  # noqa: E402
 
-from bench import reference, traffic, weights  # noqa: E402
 from bench import trace as trace_lib  # noqa: E402
+from bench import traffic  # noqa: E402
 from bench.stats import percentile  # noqa: E402
+from bench.weights import is_shape  # noqa: E402
 
-#: ArchConfig fields a configuration file states, checked against the
-#: program's registry entry after the file's overrides
-MODEL_KEYS = ("n_layers", "d_model", "n_heads", "kv_heads", "head_dim",
-              "d_ff", "vocab", "norm", "rope_theta", "tie_embeddings")
 #: requests checked against the reference after the window
 SAMPLE_N = 8
 #: an open loop keeps offering its traffic after the window closes until
@@ -112,6 +113,7 @@ class Cell:
     config: dict  # configs/<config>.json
     mix: dict  # traffic/<mix>.json
     benchmark: dict  # BENCHMARK.json
+    family: ModuleType  # families/<family>.py
 
     @property
     def model(self) -> dict:
@@ -125,10 +127,35 @@ class Cell:
 def load_cell(name: str, root: Path = BENCH,
               benchmark: Path | None = None) -> Cell:
     spec = load_json(root / "workloads" / f"{name}.json")
-    return Cell(name=name, spec=spec,
-                config=load_json(root / "configs" / f"{spec['config']}.json"),
+    config = load_json(root / "configs" / f"{spec['config']}.json")
+    return Cell(name=name, spec=spec, config=config,
                 mix=traffic.load_mix(root, spec["traffic"]),
-                benchmark=load_json(benchmark or CHECKOUT / "BENCHMARK.json"))
+                benchmark=load_json(benchmark or CHECKOUT / "BENCHMARK.json"),
+                family=load_family(root, spec["config"], config))
+
+
+#: a family's name: a module's name under ``families/``, no path
+_FAMILY_NAME = re.compile(r"[A-Za-z_][A-Za-z0-9_]{0,63}")
+
+
+def load_family(root: Path, config_name: str, config: dict) -> ModuleType:
+    """The module of the family the configuration names,
+    ``families/<family>.py`` under ``root``, else under the benchmark's own
+    directory.  A configuration that names none, or one that does not
+    exist, is refused."""
+    family = config.get("family")
+    if family is None:
+        raise ValueError(f"configuration {config_name!r} names no family "
+                         f"(\"family\": a module under families/)")
+    if not isinstance(family, str) or not _FAMILY_NAME.fullmatch(family):
+        raise ValueError(f"configuration {config_name!r}: {family!r} is not "
+                         f"a family's name")
+    path = find_file(root, "families", family)
+    if not path.exists():
+        raise ValueError(f"configuration {config_name!r} names the family "
+                         f"{family!r}, and there is no families/{family}.py "
+                         f"under {root} or {BENCH}")
+    return exec_file(path, f"bench_family_{family}")
 
 
 def cell_metrics(cell: Cell, kind: str) -> list[dict]:
@@ -138,17 +165,25 @@ def cell_metrics(cell: Cell, kind: str) -> list[dict]:
             if "workloads" not in m or cell.name in m["workloads"]]
 
 
+def find_file(root: Path, kind: str, name: str) -> Path:
+    """``<kind>/<name>.py`` under ``root`` where it is there, else under
+    the benchmark's own directory."""
+    path = root / kind / f"{name}.py"
+    return path if path.exists() else BENCH / kind / f"{name}.py"
+
+
+def exec_file(path: Path, module_name: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(root: Path, metric: str):
     """The ``read(run)`` function of ``metrics/<metric>.py`` under ``root``,
     else under the benchmark's own directory."""
-    path = root / "metrics" / f"{metric}.py"
-    if not path.exists():
-        path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return exec_file(find_file(root, "metrics", metric),
+                     f"bench_metric_{metric.replace('.', '_')}").read
 
 
 # ---------------------------------------------------------------------------
@@ -189,7 +224,8 @@ def arch_config(cell: Cell):
 
     cfg = dataclasses.replace(get_config(cell.config["arch"]),
                               **cell.config.get("overrides", {}))
-    wrong = {k: (getattr(cfg, k), cell.model[k]) for k in MODEL_KEYS
+    wrong = {k: (getattr(cfg, k), cell.model[k])
+             for k in cell.family.MODEL_KEYS
              if getattr(cfg, k) != cell.model[k]}
     if wrong:
         raise ValueError(f"{cell.config['arch']}: the program's config "
@@ -197,17 +233,18 @@ def arch_config(cell: Cell):
     return cfg
 
 
-def check_layout(api, model: dict, dtype: str) -> None:
-    """The seeded tree must have the program's parameter shapes."""
+def check_layout(api, cell: Cell, dtype: str) -> None:
+    """The family's seeded tree must have the program's parameter
+    shapes."""
     import jax
 
     want = jax.eval_shape(api.init, jax.random.PRNGKey(0))
-    have = weights.shapes(model)
+    have = cell.family.shapes(cell.model)
     flat_w = {jax.tree_util.keystr(p): (tuple(x.shape), str(x.dtype))
               for p, x in jax.tree_util.tree_flatten_with_path(want)[0]}
     flat_h = {jax.tree_util.keystr(p): (tuple(s), dtype)
               for p, s in jax.tree_util.tree_flatten_with_path(
-                  have, is_leaf=lambda x: isinstance(x, tuple))[0]}
+                  have, is_leaf=is_shape)[0]}
     if flat_w != flat_h:
         diff = sorted(set(flat_w.items()) ^ set(flat_h.items()))[:6]
         raise ValueError(f"seeded weights do not match the program's "
@@ -272,8 +309,9 @@ def set_up(cell: Cell, seed: int, control: bool = False) -> Served:
 
     cfg = arch_config(cell)
     api = build_model(cfg)
-    check_layout(api, cell.model, cfg.param_dtype)
-    w = jax.block_until_ready(weights.make(cell.model, seed, cfg.param_dtype))
+    check_layout(api, cell, cfg.param_dtype)
+    w = jax.block_until_ready(
+        cell.family.make(cell.model, seed, cfg.param_dtype))
     timings = {"weights_s": lap()}
     spec = numerics_spec(cell, control)
     served = jax.block_until_ready(
@@ -561,6 +599,10 @@ class Run:
         return self.cell.model
 
     @property
+    def family(self) -> ModuleType:
+        return self.cell.family
+
+    @property
     def slots(self) -> int:
         return self.cell.engine["slots"]
 
@@ -646,18 +688,19 @@ def sample(recs: list, seed: int) -> list:
 
 def compare(cell: Cell, seed: int, picked: list, param_dtype: str,
             bits: int | None = None) -> dict:
-    """Teacher-force the reference over each sampled request's prompt and
-    served tokens, reading at each served position the gap by which the
-    served token's logit lies below the reference's best.  Returns the
-    pooled mean gap, the largest of the requests' mean gaps, each
-    request's mean gap, the widest gap, and the share of served tokens
-    that are the reference's first choice.  ``bits`` puts the reference at
-    that many bits in the program's place: at the same positions the gap
-    read is that of the token the lower precision ranks first."""
+    """Teacher-force the family's reference over each sampled request's
+    prompt and served tokens, reading at each served position the gap by
+    which the served token's logit lies below the reference's best.
+    Returns the pooled mean gap, the largest of the requests' mean gaps,
+    each request's mean gap, the widest gap, and the share of served
+    tokens that are the reference's first choice.  ``bits`` puts the
+    reference at that many bits in the program's place: at the same
+    positions the gap read is that of the token the lower precision ranks
+    first."""
     import jax
 
-    model = cell.model
-    w = jax.block_until_ready(weights.make(model, seed, param_dtype))
+    model, family = cell.model, cell.family
+    w = jax.block_until_ready(family.make(model, seed, param_dtype))
     width = cell.engine["max_len"]
     gaps, agree = [], 0
     for r in picked:
@@ -670,10 +713,10 @@ def compare(cell: Cell, seed: int, picked: list, param_dtype: str,
         judged = np.zeros(width, np.int32)
         judged[pos] = gen
         if bits:
-            judged = np.asarray(reference.readings(
+            judged = np.asarray(family.readings(
                 w, seq, judged[None], model, bits=bits)[2])
         best, at, top = (np.asarray(x) for x in
-                         reference.readings(w, seq, judged[None], model))
+                         family.readings(w, seq, judged[None], model))
         gaps.append(best[pos] - at[0][pos])
         agree += int(np.sum(top[pos] == judged[pos]))
     del w

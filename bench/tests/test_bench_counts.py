@@ -7,6 +7,7 @@ from pathlib import Path
 import pytest
 
 from bench import counts
+from bench.families import dense_gqa
 
 BENCH = Path(__file__).resolve().parents[1]
 OLMO = json.loads((BENCH / "configs" / "olmo-1b-sd.json").read_text())["model"]
@@ -30,7 +31,7 @@ def test_q_projection_with_cv():
 
 
 def test_dense_work_sums_every_projection_of_every_layer():
-    ops, nbytes = counts.dense_work(OLMO, 1, cv=False)
+    ops, nbytes = dense_gqa.dense_work(OLMO, 1, cv=False)
     per_layer = 4 * 2048 * 2048 + 3 * 2048 * 8192
     assert nbytes > OLMO["n_layers"] * per_layer  # every weight read once
     assert ops == pytest.approx(2 * OLMO["n_layers"] * per_layer, rel=1e-3)
@@ -38,7 +39,7 @@ def test_dense_work_sums_every_projection_of_every_layer():
 
 @pytest.mark.parametrize("rows,bound", [(32, "memory"), (1024, "compute")])
 def test_roofline_bound(rows, bound):
-    t, which = counts.least_time(*counts.dense_work(OLMO, rows, cv=True),
+    t, which = counts.least_time(*dense_gqa.dense_work(OLMO, rows, cv=True),
                                  PEAKS["int8_ops_per_s"],
                                  PEAKS["hbm_bytes_per_s"])
     assert which == bound and t > 0
@@ -46,7 +47,7 @@ def test_roofline_bound(rows, bound):
 
 def test_nominal_ops():
     # olmo-1b: 1,073,741,824 projection parameters + 103,022,592 in the head
-    assert counts.matmul_params(OLMO) == 1_073_741_824 + 2048 * 50304
-    got = counts.nominal_ops(OLMO, tokens=2, context_sum=10)
+    assert dense_gqa.matmul_params(OLMO) == 1_073_741_824 + 2048 * 50304
+    got = dense_gqa.nominal_ops(OLMO, tokens=2, context_sum=10)
     attn = 4 * 16 * 16 * 128 * 10
-    assert got == 2 * counts.matmul_params(OLMO) * 2 + attn
+    assert got == 2 * dense_gqa.matmul_params(OLMO) * 2 + attn

@@ -1,8 +1,9 @@
 """The harness end to end at test size on the CPU (the look for a chip
-skipped): it finds a cell, a mix and a per-layer metric added as new files
-alone; it refuses to run without a TPU or on an unknown chip; and its
-comparison with the reference comes out false under the control and under
-each fault the serving path can have."""
+skipped): it finds a cell, a mix, a per-layer metric and a model family
+added as new files alone; it refuses to run without a TPU or on an unknown
+chip, and a configuration that names no known family; and its comparison
+with the reference comes out false under the control and under each fault
+the serving path can have."""
 
 import json
 import os
@@ -79,6 +80,158 @@ def test_new_cell_config_mix_and_metric_are_files_alone(tmp_path):
     assert res["metrics"]["tokens_per_request"]["value"] == pytest.approx(5)
     res = _run("tiny-int8-short", root=root)
     assert set(res["metrics"]) == {"ttft_p90_ms", "itl_p95_ms", "setup_s"}
+
+
+#: a family that is not in the benchmark: dense GQA with an untied head,
+#: one leaf more than ``dense_gqa``; the head is served packed, so its
+#: reference and counts are those of ``dense_gqa`` plus that layer
+TOY_FAMILY = '''
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from bench import counts
+from bench import reference as ref
+from bench.families import dense_gqa as base
+from bench.weights import make_tree
+
+MODEL_KEYS = base.MODEL_KEYS + ("qk_norm",)
+matmul_params = base.matmul_params  # the untied head is the tied one's size
+nominal_ops = base.nominal_ops
+
+
+def shapes(model):
+    return {**base.shapes(model),
+            "lm_head": {"w": (model["d_model"], model["vocab"])}}
+
+
+def make(model, seed, dtype="float32"):
+    return make_tree(shapes(model), seed, dtype, base.leaf)
+
+
+def _logits(w, tokens, model, bits):
+    f32 = functools.partial(jax.tree.map, lambda a: a.astype(jnp.float32))
+    x = w["embed"]["table"].astype(jnp.float32)[tokens]
+    x, _ = jax.lax.scan(
+        lambda x, lw: (base._layer(x, f32(lw), model, bits), None), x,
+        w["blocks"])
+    x = ref.norm(x, f32(w["final_norm"]).get("scale"), model["norm"])
+    return ref.dense(x, w["lm_head"]["w"].astype(jnp.float32), bits)
+
+
+@functools.partial(jax.jit, static_argnames=("model_items", "bits"))
+def _full(w, tokens, model_items, bits):
+    with jax.default_matmul_precision(ref.PRECISION):
+        return _logits(w, tokens, dict(model_items), bits)
+
+
+def forward(w, tokens, model, bits=None):
+    return _full(w, jnp.asarray(tokens, jnp.int32),
+                 tuple(sorted(model.items())), bits)
+
+
+def readings(w, tokens, picks, model, bits=None):
+    return ref.picked(forward(w, tokens, model, bits),
+                      jnp.asarray(picks, jnp.int32))
+
+
+def dense_work(model, rows, cv):
+    ops, nbytes = base.dense_work(model, rows, cv)
+    o, b = counts.layer_work(rows, model["d_model"], model["vocab"], cv)
+    return ops + o, nbytes + b
+'''
+
+
+def _toy_family_root(tmp_path) -> Path:
+    """The fixtures with a new family, a configuration naming it and a
+    cell on that configuration: new files, and new entries in
+    BENCHMARK.json; no file the harness had is edited."""
+    root = tmp_path / "bench"
+    shutil.copytree(FIXTURES, root)
+    (root / "families").mkdir()
+    (root / "families" / "dense_untied.py").write_text(TOY_FAMILY)
+    config = json.loads((root / "configs" / "tiny-granite.json").read_text())
+    config.update(name="tiny-granite-untied", family="dense_untied")
+    config["model"].update(tie_embeddings=False, qk_norm=False)
+    config["overrides"]["tie_embeddings"] = False
+    (root / "configs" / "tiny-granite-untied.json").write_text(
+        json.dumps(config))
+    spec = json.loads((root / "workloads" / "tiny-int8-docs.json")
+                      .read_text())
+    # the packed head adds to the served gaps: at this size sound runs read
+    # up to 0.064 / 0.159 and the int4 control 2.19 / 2.63 (CPU)
+    spec.update(config="tiny-granite-untied",
+                limits={"served_gap_mean": 0.3, "worst_request_gap": 0.6})
+    (root / "workloads" / "tiny-untied-docs.json").write_text(
+        json.dumps(spec))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-granite-untied", "file":
+                             "bench/configs/tiny-granite-untied.json"})
+    bench["workloads"].append({"name": "tiny-untied-docs", "config":
+                               "tiny-granite-untied", "traffic": "tiny-docs",
+                               "chips": 1, "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "tiny-int8-docs" in m.get("workloads", ()):
+            m["workloads"].append("tiny-untied-docs")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return root
+
+
+def test_new_family_is_files_alone(tmp_path):
+    from repro.models import build_model
+
+    root = _toy_family_root(tmp_path)
+    cell = R.load_cell("tiny-untied-docs", root=root,
+                       benchmark=root / "BENCHMARK.json")
+    assert Path(cell.family.__file__) == root / "families" / "dense_untied.py"
+    cfg = R.arch_config(cell)
+    R.check_layout(build_model(cfg), cell, cfg.param_dtype)
+    # the program's tree has the toy's extra leaf, which dense GQA lacks
+    dense = R.Cell(cell.name, cell.spec, cell.config, cell.mix,
+                   cell.benchmark, family=R.load_family(
+                       root, "tiny-granite-untied",
+                       {**cell.config, "family": "dense_gqa"}))
+    with pytest.raises(ValueError, match="lm_head"):
+        R.check_layout(build_model(cfg), dense, cfg.param_dtype)
+    # set-up, the window and the comparison with the toy's reference, and
+    # its control: the toy's reference at int4
+    res = _run("tiny-untied-docs", root=root)
+    assert res["correct"], res["checks"]
+    assert set(res["metrics"]) == {"gen_tok_per_s", "setup_s"}
+    assert res["_extra"]["served_tokens"] > 0
+    res = _run("tiny-untied-docs", root=root, control=True)
+    assert not res["correct"]
+    assert res["checks"]["served_gap_mean"]["value"] > 0.3
+
+
+def test_family_model_keys_are_checked_against_the_program(tmp_path):
+    root = _toy_family_root(tmp_path)
+    path = root / "configs" / "tiny-granite-untied.json"
+    config = json.loads(path.read_text())
+    config["model"]["qk_norm"] = True  # dense_gqa does not check it
+    path.write_text(json.dumps(config))
+    cell = R.load_cell("tiny-untied-docs", root=root,
+                       benchmark=root / "BENCHMARK.json")
+    with pytest.raises(ValueError, match="qk_norm"):
+        R.arch_config(cell)
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family", "../run"],
+                         ids=["missing", "unknown", "a_path"])
+def test_configuration_without_a_known_family_is_refused(tmp_path, family):
+    root = _toy_family_root(tmp_path)
+    path = root / "configs" / "tiny-granite-untied.json"
+    config = json.loads(path.read_text())
+    if family is None:
+        del config["family"]
+    else:
+        config["family"] = family
+    path.write_text(json.dumps(config))
+    with pytest.raises(ValueError, match="tiny-granite-untied") as err:
+        R.load_cell("tiny-untied-docs", root=root,
+                    benchmark=root / "BENCHMARK.json")
+    assert family is None or family in str(err.value)
 
 
 def test_sound_run_reports_every_metric():

@@ -1,6 +1,6 @@
-"""The plain reference against the program's own float forward at a tiny
-olmo and a tiny granite size (CPU, float32 compute), and the control's
-lower precision moving it."""
+"""The dense GQA family's plain reference against the program's own float
+forward at a tiny olmo and a tiny granite size (CPU, float32 compute), and
+the control's lower precision moving it."""
 
 import dataclasses
 import json
@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, weights
+from bench import reference
+from bench.families import dense_gqa
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 
@@ -31,10 +32,10 @@ def test_reference_matches_the_program_float_forward(name):
     from repro.models import build_model
 
     model, cfg = _tiny(name)
-    w = weights.make(model, 2**33 + 1, "float32")
+    w = dense_gqa.make(model, 2**33 + 1, "float32")
     toks = np.random.default_rng(0).integers(0, model["vocab"],
                                              reference.Q_BLOCK)
-    ref = np.asarray(reference.forward(w, toks, model))
+    ref = np.asarray(dense_gqa.forward(w, toks, model))
     with jax.default_matmul_precision("highest"):
         got = np.asarray(build_model(cfg).forward(
             w, {"tokens": jnp.asarray(toks[None], jnp.int32)}))[0]
@@ -43,14 +44,14 @@ def test_reference_matches_the_program_float_forward(name):
 
 def test_readings_agree_with_the_full_logits():
     model, _ = _tiny("tiny-granite")
-    w = weights.make(model, 3, "float32")
+    w = dense_gqa.make(model, 3, "float32")
     toks = np.random.default_rng(1).integers(0, model["vocab"],
                                              reference.Q_BLOCK)
     picks = np.random.default_rng(2).integers(0, model["vocab"],
                                               (2, reference.Q_BLOCK))
-    full = np.asarray(reference.forward(w, toks, model))
+    full = np.asarray(dense_gqa.forward(w, toks, model))
     best, at, top = (np.asarray(x) for x in
-                     reference.readings(w, toks, picks, model))
+                     dense_gqa.readings(w, toks, picks, model))
     np.testing.assert_allclose(best, full.max(-1), rtol=1e-6)
     np.testing.assert_allclose(at, np.take_along_axis(
         full[None], picks[..., None], -1)[..., 0], rtol=1e-6)
@@ -59,19 +60,19 @@ def test_readings_agree_with_the_full_logits():
 
 def test_control_is_lower_precision():
     model, _ = _tiny("tiny-olmo")
-    w = weights.make(model, 4, "float32")
+    w = dense_gqa.make(model, 4, "float32")
     toks = np.arange(reference.Q_BLOCK) % model["vocab"]
-    ref = np.asarray(reference.forward(w, toks, model))
-    ctl = np.asarray(reference.forward(w, toks, model, bits=4))
+    ref = np.asarray(dense_gqa.forward(w, toks, model))
+    ctl = np.asarray(dense_gqa.forward(w, toks, model, bits=4))
     rel = np.linalg.norm(ctl - ref) / np.linalg.norm(ref)
     assert rel > 0.05
 
 
 def test_weights_are_seeded():
     model, _ = _tiny("tiny-olmo")
-    a = weights.make(model, 2**33 + 9, "float32")
-    b = weights.make(model, 2**33 + 9, "float32")
-    c = weights.make(model, 2**33 + 10, "float32")
+    a = dense_gqa.make(model, 2**33 + 9, "float32")
+    b = dense_gqa.make(model, 2**33 + 9, "float32")
+    c = dense_gqa.make(model, 2**33 + 10, "float32")
     la, lb, lc = (jax.tree.leaves(t) for t in (a, b, c))
     assert all(np.array_equal(x, y) for x, y in zip(la, lb))
     assert not np.array_equal(la[0], lc[0])

@@ -18,7 +18,7 @@ import pytest
 from bench import engine_trace as et
 from bench import readings
 from bench import run as R
-from bench import trace, weights
+from bench import trace
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 BENCH = Path(__file__).resolve().parents[1]
@@ -43,10 +43,11 @@ def _engine(config_name: str, numerics: str):
     from repro.serving import ServingEngine
 
     config = R.load_json(FIXTURES / "configs" / f"{config_name}.json")
-    cell = R.Cell(config_name, {}, config, {}, {})
+    cell = R.Cell(config_name, {}, config, {}, {},
+                  family=R.load_family(FIXTURES, config_name, config))
     cfg = R.arch_config(cell)
     api = build_model(cfg)
-    w = weights.make(cell.model, 3, cfg.param_dtype)
+    w = cell.family.make(cell.model, 3, cfg.param_dtype)
     served = build_serving_params(
         w, cfg, ServeConfig(spec=get_preset(numerics), fold=False))
     return ServingEngine(cfg, served, EngineConfig(**config["engine"]),
@@ -296,18 +297,24 @@ def test_new_readers_on_the_recorded_trace(recorded):
 # ---------------------------------------------------------------------------
 
 
-def test_earlier_readers_unchanged_on_the_recorded_trace():
-    """The readings ``trace_small.json.gz`` gave when the engine's spans
-    and scopes came: they read the benchmark's record alone, which the
-    engine's record (``run.trace["engine"]``) leaves as it was."""
+def _recorded_run() -> R.Run:
+    """A run of ``olmo1b-sd-chat`` holding ``trace_small.json.gz``."""
     rec = json.load(gzip.open(FIXTURES / "trace_small.json.gz", "rt"))
     steps = [R.Step(0.0, 0.07, "decode", 0, 32, 32 * 400.0, True),
              R.Step(0.07, 0.2, "chunk", 768, 8, 768 * 300.0, True)]
     win = R.Window(t_lead=0.0, t0=0.0, t1=1.0, t_drained=1.0, recs=[],
                    all_recs=[], steps=steps, compiles=0)
     peaks = R.load_json(BENCH / "peaks.json")["devices"]["TPU v5 lite"]
-    run = R.Run(cell=R.load_cell("olmo1b-sd-chat"), window=win, setup_s=0.0,
-                peaks=peaks, cv=True, trace=rec)
+    return R.Run(cell=R.load_cell("olmo1b-sd-chat"), window=win,
+                 setup_s=0.0, peaks=peaks, cv=True, trace=rec)
+
+
+def test_earlier_readers_unchanged_on_the_recorded_trace():
+    """The readings ``trace_small.json.gz`` gave when the engine's spans
+    and scopes came: they read the benchmark's record alone, which the
+    engine's record (``run.trace["engine"]``) leaves as it was."""
+    run = _recorded_run()
+    rec = run.trace
     assert readings.step_ms(run, "decode") == pytest.approx(65.584443)
     assert readings.step_ms(run, "chunk") == pytest.approx(119.960776)
     assert readings.dense_roofline(run) == pytest.approx(12.777114960626095)
@@ -324,3 +331,17 @@ def test_earlier_readers_unchanged_on_the_recorded_trace():
     rec["engine"] = dict(EMPTY)
     assert R.load_reader(BENCH, "kv_stack_share.chat")(run) is None
     assert readings.step_ms(run, "chunk") == pytest.approx(119.960776)
+
+
+def test_readings_count_through_the_cells_family():
+    """``dense_roofline`` and ``step_mfu`` take the work from the family
+    the configuration names: a family that counts twice the work reads
+    twice the shares."""
+    run = _recorded_run()
+    dense = run.cell.family
+    run.cell.family = SimpleNamespace(
+        dense_work=lambda *a: tuple(2 * x for x in dense.dense_work(*a)),
+        nominal_ops=lambda *a: 2 * dense.nominal_ops(*a))
+    assert readings.dense_roofline(run) == pytest.approx(
+        2 * 12.777114960626095)
+    assert readings.step_mfu(run) == pytest.approx(2 * 2.6516008434580036)
