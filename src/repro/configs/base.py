@@ -10,6 +10,8 @@ the same family (small depth/width/vocab/experts — structure preserved).
 from __future__ import annotations
 
 import dataclasses
+import math
+from collections.abc import Mapping
 from typing import Literal
 
 
@@ -84,6 +86,54 @@ class EngineConfig:
     shadow_fraction: float = 0.0
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class YaRN:
+    """A published ``rope_scaling`` of type ``yarn`` (DeepSeek-V2's form),
+    under its published keys.  Equal to the mapping it was read from, and
+    hashable, so an :class:`ArchConfig` holding it stays a static value."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+    type: str = "yarn"
+
+    def _items(self) -> dict:
+        return dataclasses.asdict(self)
+
+    def __eq__(self, other):
+        if isinstance(other, Mapping):
+            return self._items() == dict(other)
+        if isinstance(other, YaRN):
+            return self._items() == other._items()
+        return NotImplemented
+
+    def __hash__(self) -> int:
+        return hash(tuple(self._items().items()))
+
+    @staticmethod
+    def m(scale: float, mscale: float) -> float:
+        """YaRN's attention factor ``0.1 * mscale * ln(scale) + 1``."""
+        return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+    @property
+    def softmax_factor(self) -> float:
+        """What the softmax scale is multiplied by: ``m(factor,
+        mscale_all_dim)**2`` (1 when ``mscale_all_dim`` is 0)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self.m(self.factor, self.mscale_all_dim) ** 2
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """What cos and sin are multiplied by: ``m(factor, mscale) /
+        m(factor, mscale_all_dim)``."""
+        return (self.m(self.factor, self.mscale)
+                / self.m(self.factor, self.mscale_all_dim))
+
+
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
@@ -102,6 +152,8 @@ class ArchConfig:
     rope: Literal["rope", "mrope", "none"] = "rope"
     rope_theta: float = 10000.0
     mrope_sections: tuple[int, int, int] = (16, 24, 24)
+    #: YaRN context extension of the MLA rope (None: plain rope)
+    rope_scaling: YaRN | None = None
 
     # attention
     attn: Literal["gqa", "mla", "none"] = "gqa"
@@ -123,6 +175,14 @@ class ArchConfig:
     n_shared_experts: int = 0
     d_ff_expert: int = 0
     first_dense_layers: int = 0
+    #: False: the gates are the top-k softmax probabilities as they are
+    #: (DeepSeek-V2-Lite); True: renormalised to sum to one (Moonlight)
+    norm_topk_prob: bool = True
+    #: the routed experts this chip holds, [expert_offset, expert_offset +
+    #: experts_held) of n_experts (0: all of them); the layer computes only
+    #: their part of the result, the router still scores all n_experts
+    experts_held: int = 0
+    expert_offset: int = 0
     moe_impl: str = "local"  # "local" | "ep_psum" (launch overrides for pods)
     capacity_factor: float = 1.25
 
@@ -183,6 +243,7 @@ class ArchConfig:
             qk_rope_dim=self.qk_rope_dim,
             v_head_dim=self.v_head_dim,
             rope_theta=self.rope_theta,
+            rope_scaling=self.rope_scaling,
         )
 
     def moe_config(self):
@@ -196,6 +257,9 @@ class ArchConfig:
             n_shared=self.n_shared_experts,
             capacity_factor=self.capacity_factor,
             impl=self.moe_impl,
+            norm_topk_prob=self.norm_topk_prob,
+            experts_held=self.experts_held,
+            expert_offset=self.expert_offset,
         )
 
     def ssm_config(self):

@@ -94,8 +94,10 @@ class QuantizedDenseGroup:
 
 
 def is_linear_params(p: Any) -> bool:
-    """Float linear leaf: 2D weights, or 3D = (layers, k, n) scanned stack."""
-    return isinstance(p, dict) and "w" in p and getattr(p["w"], "ndim", 0) in (2, 3)
+    """Float linear leaf: 2D weights, 3D = (layers, k, n) scanned stack or
+    (experts, k, n) expert stack, or 4D = (layers, experts, k, n) expert
+    stacks of scanned MoE layers."""
+    return isinstance(p, dict) and "w" in p and getattr(p["w"], "ndim", 0) in (2, 3, 4)
 
 
 def _packed_forward(p: QuantizedDense | QuantizedDenseGroup,
@@ -195,28 +197,32 @@ def _pack_stack(w: jax.Array, b: jax.Array, *, mode, m: int,
 
 
 def _pack_leaf(p: dict, policy: ApproxPolicy) -> PackedLinear:
-    """Quantize one float linear leaf (2D, or layer by layer over a 3D
-    stack)."""
+    """Quantize one float linear leaf (2D, or matrix by matrix over the
+    leading axes of a 3D or 4D stack)."""
     w = p["w"]
     b = p.get("b")
-    if w.ndim == 3:
+    if w.ndim >= 3:
+        lead, (k, n) = w.shape[:-2], w.shape[-2:]
         pack = _pack_stack(
-            w, b if b is not None
-            else jnp.zeros((w.shape[0], w.shape[-1]), w.dtype),
+            w.reshape(-1, k, n),
+            b.reshape(-1, n) if b is not None
+            else jnp.zeros((math.prod(lead), n), w.dtype),
             mode=policy.mode, m=policy.m, groups=policy.groups)
         if b is None:
             pack = dataclasses.replace(pack, bias=None)
-        return pack
+        return jax.tree.map(lambda a: a.reshape(lead + a.shape[1:]), pack)
     return pack_linear(w, b, policy.mode, policy.m, policy.groups)
 
 
 def _act_qp(act_range, w: jax.Array) -> QuantParams:
-    """Activation quant params; per-layer vectors for 3D stacks so
-    ``lax.scan`` can slice the pack."""
-    if w.ndim == 3:
+    """Activation quant params; per-matrix arrays for stacks so
+    ``lax.scan`` can slice the pack (and the grouped expert path index
+    it by expert)."""
+    if w.ndim >= 3:
+        lead = w.shape[:-2]
         return calibrate_minmax(
-            jnp.broadcast_to(jnp.asarray(act_range[0], jnp.float32), (w.shape[0],)),
-            jnp.broadcast_to(jnp.asarray(act_range[1], jnp.float32), (w.shape[0],)),
+            jnp.broadcast_to(jnp.asarray(act_range[0], jnp.float32), lead),
+            jnp.broadcast_to(jnp.asarray(act_range[1], jnp.float32), lead),
         )
     return calibrate_minmax(act_range[0], act_range[1])
 
@@ -228,10 +234,10 @@ def _maybe_blocked(pack: PackedLinear, a_qp: QuantParams,
         return None
     k, n = pack.w_q.shape[-2:]
     bn, bk = serving_blocks(k, n)
-    if ndim == 3:
-        return jax.vmap(
-            lambda pk, aq: build_blocked_layout(pk, aq, bn, bk))(pack, a_qp)
-    return build_blocked_layout(pack, a_qp, bn, bk)
+    build = lambda pk, aq: build_blocked_layout(pk, aq, bn, bk)  # noqa: E731
+    for _ in range(ndim - 2):
+        build = jax.vmap(build)
+    return build(pack, a_qp)
 
 
 def _maybe_fold(pack: PackedLinear, a_qp: QuantParams,

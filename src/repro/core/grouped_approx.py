@@ -34,10 +34,26 @@ def _row_expert_ids(group_sizes: jax.Array, m_rows: int) -> jax.Array:
 
 
 def _ragged_int_dot(a, w, group_sizes) -> jax.Array:
-    """Exact grouped integer matmul: (M, k) x (E, k, n) -> (M, n) int32."""
-    return jax.lax.ragged_dot(
-        a.astype(jnp.int32), w.astype(jnp.int32), group_sizes,
+    """Exact grouped integer matmul of codes in [0, 255]:
+    (M, k) x (E, k, n) -> (M, n) int32; rows past the groups are 0.
+
+    The operands go in as signed bytes, ``code - 128``, the form the TPU's
+    grouped matmul takes (with int32 operands XLA computes every row
+    against every expert).  The shift's terms are put back exactly:
+    ``sum a w = sum a'w' + 128 (sum a' + sum w') + 128^2 k``.
+    """
+    m_rows, k = a.shape
+    a_s = a.astype(jnp.int32) - 128
+    w_s = jnp.asarray(w, jnp.int32) - 128
+    acc = jax.lax.ragged_dot(
+        a_s.astype(jnp.int8), w_s.astype(jnp.int8), group_sizes,
         preferred_element_type=jnp.int32)
+    col = jnp.sum(w_s, axis=1)  # (E, n): sum over k of w' per expert
+    ids = _row_expert_ids(group_sizes, m_rows)
+    acc = (acc + 128 * (jnp.sum(a_s, axis=-1, keepdims=True) + col[ids])
+           + 128 * 128 * k)
+    in_group = jnp.arange(m_rows) < jnp.sum(group_sizes)
+    return jnp.where(in_group[:, None], acc, 0)
 
 
 def _approx_ragged(a_i32, w_q, group_sizes, mode: str, m: int) -> jax.Array:

@@ -164,7 +164,7 @@ def _mla_full(bp, h, cfg: ArchConfig, positions, want_cache: bool):
         b, t, mcfg.n_heads, mcfg.qk_nope_dim + mcfg.v_head_dim
     )
     k_nope, v = kv[..., : mcfg.qk_nope_dim], kv[..., mcfg.qk_nope_dim :]
-    scale = mcfg.qk_head_dim**-0.5
+    scale = mcfg.softmax_scale
 
     def score_chunk(qn, qr, q_off):
         logits = (
@@ -846,7 +846,7 @@ def _mla_slots(bp, h, lc: dict, lengths, n_valid, cfg: ArchConfig, positions,
             mcfg.qk_nope_dim + mcfg.v_head_dim)
         w_uk, w_uv = w_b[..., : mcfg.qk_nope_dim], w_b[..., mcfg.qk_nope_dim:]
         q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_uk)
-        scale = mcfg.qk_head_dim**-0.5
+        scale = mcfg.softmax_scale
         lat = lat_c.astype(h.dtype)
         logits = (
             jnp.einsum("bqhr,bkr->bhqk", q_lat, lat)
@@ -880,6 +880,8 @@ STEP_SCOPES = ("embed", "attn_norm", "qkv", "kv_write", "attn", "o_proj",
 def _block_decode_slots(bp: dict, x, lc: dict, lengths, n_valid,
                         cfg: ArchConfig, positions, mesh, block_tables=None,
                         layer=None):
+    """One block of :func:`decode_slots`: (x, new cache leaves, the MoE
+    layer's counts: int32[2], None for a dense block)."""
     with jax.named_scope("attn_norm"):
         h = apply_norm(cfg.norm, bp["attn_norm"], x)
     pool_lc = None
@@ -900,22 +902,25 @@ def _block_decode_slots(bp: dict, x, lc: dict, lengths, n_valid,
         x = (x + a).astype(x.dtype)
     with jax.named_scope("mlp_norm"):
         h = apply_norm(cfg.norm, bp["mlp_norm"], x)
+    counts = None
     if cfg.mlp == "moe" and "router" in bp["mlp"]:
-        # the routed experts' in and out products are not told apart
+        # the layer's own scopes (router, experts, shared_expert) nest here
         with jax.named_scope("mlp_in"):
-            m = moe_lib.moe_apply(bp["mlp"], h, cfg.moe_config(), mesh=mesh)
+            m, counts = moe_lib.moe_apply(bp["mlp"], h, cfg.moe_config(),
+                                          mesh=mesh, with_counts=True)
     elif cfg.mlp == "gelu":
         m = gelu_mlp(bp["mlp"], h)
     else:
         m = swiglu(bp["mlp"], h)
     with jax.named_scope("mlp_out"):
-        return (x + m).astype(x.dtype), new
+        return (x + m).astype(x.dtype), new, counts
 
 
 def decode_slots(params: Params, tokens: jax.Array, cache: dict,
                  cfg: ArchConfig, n_valid: jax.Array,
                  mesh=None, block_tables=None,
-                 unroll_layers: bool = False) -> tuple[jax.Array, dict]:
+                 unroll_layers: bool = False,
+                 moe_counts: bool = False) -> tuple[jax.Array, dict]:
     """Fixed-shape continuous-batching step.
 
     tokens: (slots, C) int32 — row b's first ``n_valid[b]`` entries are real
@@ -964,6 +969,11 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
     The approximation-error probe (:mod:`repro.quant.error_probe`) runs
     its eager single-row forwards this way; the jitted serving step never
     sets it (the scan keeps HLO size O(1) in depth).
+
+    ``moe_counts`` (models with MoE layers): a third output, int32[2],
+    summed over the MoE layers: the (token, held expert) pairs the call
+    computed, padding rows' included, and the (layer, held expert) groups
+    given at least one row.
     """
     reason = _slot_unsupported(cfg)
     if reason is not None:
@@ -993,8 +1003,9 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
             with jax.named_scope("kv_write"):
                 lc, layer = {k: v[i] for k, v in stacked.items()}, None
         with observers.scope("dense_blocks", i):
-            x, new = _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
-                                         positions, mesh, block_tables, layer)
+            x, new, _ = _block_decode_slots(bp, x, lc, lengths, n_valid,
+                                            cfg, positions, mesh,
+                                            block_tables, layer)
         with jax.named_scope("kv_write"):
             for k in dense_keys:
                 new_cache[f"dense_{k}"] = (
@@ -1004,39 +1015,45 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
     lcs = {k: cache[k] for k in layer_keys}
     n_layers = lcs[layer_keys[0]].shape[0]
 
+    def block(bp, x, lc, **kw):
+        return _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
+                                   positions, mesh, **kw)
+
     if unroll_layers:
         acc: dict[str, list] = {k: [] for k in layer_keys}
+        counts = []
         for i in range(n_layers):
             bp = jax.tree.map(lambda a: a[i], params["blocks"])
             lc = {k: lcs[k][i] for k in layer_keys}
             with observers.scope("blocks", i):
-                x, new = _block_decode_slots(bp, x, lc, lengths, n_valid,
-                                             cfg, positions, mesh,
-                                             block_tables)
+                x, new, cnt = block(bp, x, lc, block_tables=block_tables)
             for k in layer_keys:
                 acc[k].append(new[k])
+            counts.append(cnt)
         new_layers = {k: jnp.stack(acc[k]) for k in layer_keys}
+        counts = None if counts[0] is None else jnp.stack(counts)
     elif in_place:
         # the stacked cache rides the carry: each layer writes its columns
         # into it and reads its own stripe back, nothing is restacked
         def body(carry, inp):
             x, kv = carry
             bp, i = inp
-            return _block_decode_slots(bp, x, kv, lengths, n_valid, cfg,
-                                       positions, mesh, layer=i), None
+            x, kv, cnt = block(bp, x, kv, layer=i)
+            return (x, kv), cnt
 
         with jax.named_scope("layer_stack"):
-            (x, new_layers), _ = jax.lax.scan(
+            (x, new_layers), counts = jax.lax.scan(
                 body, (x, lcs),
                 (params["blocks"], jnp.arange(n_layers, dtype=jnp.int32)))
     else:
         def body(x, inp):
             bp, lc = inp
-            return _block_decode_slots(bp, x, lc, lengths, n_valid, cfg,
-                                       positions, mesh, block_tables)
+            x, new, cnt = block(bp, x, lc, block_tables=block_tables)
+            return x, (new, cnt)
 
         with jax.named_scope("layer_stack"):
-            x, new_layers = jax.lax.scan(body, x, (params["blocks"], lcs))
+            x, (new_layers, counts) = jax.lax.scan(
+                body, x, (params["blocks"], lcs))
     new_cache.update(new_layers)
     with jax.named_scope("kv_write"):
         new_cache["lengths"] = lengths + n_valid
@@ -1045,6 +1062,8 @@ def decode_slots(params: Params, tokens: jax.Array, cache: dict,
         x = apply_norm(cfg.norm, params["final_norm"], x)
     with jax.named_scope("head"):
         logits = _logits_head(params, x)
+    if moe_counts and counts is not None:
+        return logits, new_cache, counts.sum(0)
     return logits, new_cache
 
 

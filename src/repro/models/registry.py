@@ -57,7 +57,9 @@ class ModelApi:
     # fixed-shape multi-token step with per-slot cursors
     init_slot_cache: Callable = None  # (slots, max_len, dtype) -> cache
     # (params, tokens, cache, n_valid, mesh=None, block_tables=None);
-    # block_tables selects the paged layout (repro.serving.paged)
+    # block_tables selects the paged layout (repro.serving.paged);
+    # moe_counts=True (MoE archs) adds the MoE layers' counts as a third
+    # output
     decode_slots: Callable = None
     # paged layout: (num_blocks, block_size, slots, dtype) -> block-pool cache
     init_paged_cache: Callable = None
@@ -117,9 +119,9 @@ def build_model(cfg: ArchConfig) -> ModelApi:
         # unroll_layers: eager python-loop layer stack for the error probe
         # (repro.quant.error_probe); jitted serving keeps the lax.scan
         decode_slots=lambda p, t, c, n_valid, mesh=None, block_tables=None,
-            unroll_layers=False:
+            unroll_layers=False, moe_counts=False:
             m.decode_slots(p, t, c, cfg, n_valid, mesh, block_tables,
-                           unroll_layers),
+                           unroll_layers, moe_counts),
         init_paged_cache=lambda num_blocks, block_size, slots,
             dtype=jnp.bfloat16:
             m.init_paged_slot_cache(cfg, num_blocks, block_size, slots, dtype),

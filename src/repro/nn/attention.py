@@ -276,10 +276,26 @@ class MLAConfig:
     qk_rope_dim: int = 64
     v_head_dim: int = 128
     rope_theta: float = 10000.0
+    #: YaRN (:class:`repro.configs.base.YaRN`): its rope frequencies and
+    #: its softmax factor; None: plain rope
+    rope_scaling: object = None
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_dim + self.qk_rope_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        """``qk_head_dim**-0.5``, times YaRN's ``m(factor,
+        mscale_all_dim)**2`` when it scales the rope."""
+        scale = self.qk_head_dim**-0.5
+        if self.rope_scaling is not None:
+            scale *= self.rope_scaling.softmax_factor
+        return scale
+
+    def rope_angles(self, positions: jax.Array):
+        return rope_angles(positions, self.qk_rope_dim, self.rope_theta,
+                           self.rope_scaling)
 
 
 def init_mla(key, cfg: MLAConfig, dtype=jnp.float32) -> dict:
@@ -308,7 +324,7 @@ def _mla_q(p, x, cfg: MLAConfig, positions):
     b, t, _ = x.shape
     q = dense(p["q"], x, name="q").reshape(b, t, cfg.n_heads, cfg.qk_head_dim)
     q_nope, q_rope = q[..., : cfg.qk_nope_dim], q[..., cfg.qk_nope_dim :]
-    cos, sin = rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = cfg.rope_angles(positions)
     q_rope = apply_rope(q_rope, cos, sin)
     return q_nope, q_rope
 
@@ -317,7 +333,7 @@ def _mla_latent(p, x, cfg: MLAConfig, positions):
     kv_a = dense(p["kv_a"], x, name="kv_a")
     latent = rmsnorm(p["kv_a_norm"], kv_a[..., : cfg.kv_lora_rank])
     k_rope = kv_a[..., cfg.kv_lora_rank :][:, :, None, :]  # (B, T, 1, dr)
-    cos, sin = rope_angles(positions, cfg.qk_rope_dim, cfg.rope_theta)
+    cos, sin = cfg.rope_angles(positions)
     k_rope = apply_rope(k_rope, cos, sin)[:, :, 0, :]  # shared across heads
     return latent, k_rope
 
@@ -334,7 +350,7 @@ def mla_prefill(p, x, cfg: MLAConfig, positions=None) -> jax.Array:
     )
     k_nope, v = kv[..., : cfg.qk_nope_dim], kv[..., cfg.qk_nope_dim :]
 
-    scale = cfg.qk_head_dim**-0.5
+    scale = cfg.softmax_scale
     logits = (
         jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
         + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)
@@ -380,7 +396,7 @@ def mla_decode_step(p, x, cache: dict, cfg: MLAConfig) -> tuple[jax.Array, dict]
     w_uk, w_uv = w_b[..., : cfg.qk_nope_dim], w_b[..., cfg.qk_nope_dim :]
 
     q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_uk)  # absorbed q
-    scale = cfg.qk_head_dim**-0.5
+    scale = cfg.softmax_scale
     lat = lat_c.astype(x.dtype)
     logits = (
         jnp.einsum("bqhr,bkr->bhqk", q_lat, lat)

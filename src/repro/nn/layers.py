@@ -9,6 +9,7 @@ can swap them for approximate packed versions.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Literal
 
 import jax
@@ -138,17 +139,49 @@ def gelu_mlp(p: dict, x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
-    """Inverse frequencies, shape (head_dim // 2,)."""
-    return 1.0 / (
+def yarn_range(head_dim: int, theta: float, yarn) -> tuple[int, int]:
+    """YaRN's correction range (DeepSeek-V2): the frequency indices
+    ``floor(corr(beta_fast))`` and ``ceil(corr(beta_slow))``, clamped to
+    [0, head_dim - 1], where ``corr(r) = d ln(L / (2 pi r)) / (2 ln theta)``
+    and L is the original context."""
+    def corr(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    return (max(math.floor(corr(yarn.beta_fast)), 0),
+            min(math.ceil(corr(yarn.beta_slow)), head_dim - 1))
+
+
+def rope_freqs(head_dim: int, theta: float = 10000.0,
+               scaling=None) -> jax.Array:
+    """Inverse frequencies, shape (head_dim // 2,).  ``scaling`` (a
+    :class:`repro.configs.base.YaRN`) blends each frequency with itself
+    divided by the factor: ``inv/factor * ramp + inv * (1 - ramp)``, ramp
+    rising linearly from 0 to 1 over :func:`yarn_range`."""
+    inv = 1.0 / (
         theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
     )
+    if scaling is None:
+        return inv
+    low, high = yarn_range(head_dim, theta, scaling)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
 
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float = 10000.0):
-    """cos/sin tables for integer positions (..., T) -> (..., T, head_dim//2)."""
-    ang = positions[..., None].astype(jnp.float32) * rope_freqs(head_dim, theta)
-    return jnp.cos(ang), jnp.sin(ang)
+def rope_angles(positions: jax.Array, head_dim: int, theta: float = 10000.0,
+                scaling=None):
+    """cos/sin tables for integer positions (..., T) -> (..., T, head_dim//2);
+    under YaRN ``scaling`` its frequencies and its cos/sin factor."""
+    ang = positions[..., None].astype(jnp.float32) * rope_freqs(
+        head_dim, theta, scaling)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.cos_sin_factor != 1.0:
+        cos, sin = cos * scaling.cos_sin_factor, sin * scaling.cos_sin_factor
+    return cos, sin
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

@@ -1,15 +1,20 @@
 """Mixture-of-Experts layer: shared experts + top-k routed experts with
 dropless sort-based grouped GEMM, and expert parallelism via shard_map.
 
-Routing follows DeepSeek-V2-lite / Moonlight: softmax router, top-k (k=6)
-over 64 routed experts with renormalized gates, plus always-on shared
-experts.
+Routing follows DeepSeek-V2-lite / Moonlight: softmax router over every
+routed expert, greedy top-k (k=6 of 64), plus always-on shared experts.
+The gates are the top-k probabilities as they are
+(``norm_topk_prob=False``, DeepSeek-V2-Lite) or renormalised to sum to one
+(``True``, Moonlight).
 
 Execution strategies (cfg-selected, identical math):
 
-  local      all experts on every device: sort tokens by expert ->
-             `jax.lax.ragged_dot` grouped GEMM -> unsort.  Used on single
-             host and as the per-shard body under EP.
+  local      the experts this device holds: all of them, or the chip's
+             share of an expert-parallel deployment (``experts_held``
+             from ``expert_offset``).  Sort the (token, held expert) pairs
+             by expert -> `jax.lax.ragged_dot` grouped GEMM -> unsort.
+             The absent experts' part is left out: on one chip the layer
+             runs without the exchange.  Also the per-shard body under EP.
   ep_psum    expert stacks sharded over the "model" mesh axis inside
              shard_map.  Each shard selects the (token, expert) pairs that
              hit its local experts (capacity-bounded, GShard-style drops),
@@ -17,6 +22,10 @@ Execution strategies (cfg-selected, identical math):
              buffer and psums over "model".  Comm = one all-reduce of the
              token activations per MoE layer — the collective-bound baseline
              the §Perf hillclimb attacks with the a2a dispatch variant.
+
+Named scopes split the layer in a trace: ``router`` (logits, softmax,
+top-k, sort, group sizes), ``experts`` (gather, grouped products, gate
+weighting, combine) and ``shared_expert``.
 
 The router stays float (policy functions skip "router"); expert weight
 stacks are (E, k, n) linear leaves, so `pack_params` gives every expert its
@@ -38,6 +47,10 @@ from repro.core.approx_linear import dense, init_dense
 from repro.nn.layers import init_swiglu, swiglu
 
 
+#: the layer's named scopes, nested in the step's ``mlp_in``
+SCOPES = ("router", "experts", "shared_expert")
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     d_model: int
@@ -48,14 +61,22 @@ class MoEConfig:
     capacity_factor: float = 1.25
     impl: str = "local"  # "local" | "ep_psum"
     ep_axis: str = "model"
+    norm_topk_prob: bool = True  # renormalise the top-k gates
+    experts_held: int = 0  # routed experts held here (0: all)
+    expert_offset: int = 0  # the first of them
+
+    @property
+    def held(self) -> int:
+        """How many routed experts this layer's stacks hold."""
+        return self.experts_held or self.n_experts
 
 
 def init_moe(key, cfg: MoEConfig, dtype=jnp.float32) -> dict:
     kr, ks, kg, ku, kd = jax.random.split(key, 5)
-    d, f, e = cfg.d_model, cfg.d_ff_expert, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.d_ff_expert, cfg.held
     scale = d**-0.5
     p = {
-        "router": init_dense(kr, d, e, bias=False, dtype=jnp.float32),
+        "router": init_dense(kr, d, cfg.n_experts, bias=False, dtype=dtype),
         "experts": {
             "gate": {"w": (jax.random.normal(kg, (e, d, f)) * scale).astype(dtype)},
             "up": {"w": (jax.random.normal(ku, (e, d, f)) * scale).astype(dtype)},
@@ -68,11 +89,17 @@ def init_moe(key, cfg: MoEConfig, dtype=jnp.float32) -> dict:
 
 
 def _route(p: dict, x_flat: jax.Array, cfg: MoEConfig):
-    """Top-k routing with renormalized gates.  x_flat: (N, D)."""
-    logits = dense(p["router"], x_flat.astype(jnp.float32), name="router")
+    """Softmax over every routed expert, greedy top-k; the gates
+    renormalised only under ``norm_topk_prob``.  Logits and softmax in
+    float32 whatever the router's dtype.  x_flat: (N, D)."""
+    router = p["router"]
+    if isinstance(router, dict):  # a float leaf (the packs keep it so)
+        router = {k: v.astype(jnp.float32) for k, v in router.items()}
+    logits = dense(router, x_flat.astype(jnp.float32), name="router")
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, cfg.top_k)  # (N, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return gates, idx, probs
 
 
@@ -99,46 +126,57 @@ def _moe_local(p: dict, x_flat: jax.Array, cfg: MoEConfig,
                e_start: int, e_local: int, capacity: int | None):
     """Dropless (or capacity-bounded) MoE over experts [e_start, e_start+e_local).
 
-    Returns the combined routed-expert output for the local token buffer.
+    Returns the combined routed-expert output for the local token buffer,
+    and the step's counts: the (token, local expert) pairs computed and the
+    local experts given at least one row (int32[2]).
     """
     n, d = x_flat.shape
     k = cfg.top_k
-    gates, idx, _ = _route(p, x_flat, cfg)
+    with jax.named_scope("router"):
+        gates, idx, _ = _route(p, x_flat, cfg)
 
-    pair_expert = idx.reshape(-1)  # (N*k,)
-    pair_gate = gates.reshape(-1)
-    pair_token = jnp.repeat(jnp.arange(n), k)
+        pair_expert = idx.reshape(-1)  # (N*k,)
+        pair_gate = gates.reshape(-1)
+        pair_token = jnp.repeat(jnp.arange(n), k)
 
-    local = (pair_expert >= e_start) & (pair_expert < e_start + e_local)
-    # sort pairs: non-local pairs pushed to the end, locals ordered by expert
-    sort_key = jnp.where(local, pair_expert - e_start, e_local)
-    order = jnp.argsort(sort_key, stable=True)
-    if capacity is not None and capacity < order.shape[0]:
-        order = order[:capacity]
-    sel_expert = sort_key[order]  # e_local == "dropped/non-local"
-    sel_valid = sel_expert < e_local
-    sel_token = pair_token[order]
-    sel_gate = jnp.where(sel_valid, pair_gate[order], 0.0)
+        local = (pair_expert >= e_start) & (pair_expert < e_start + e_local)
+        # sort pairs: non-local pairs pushed to the end, locals ordered by
+        # expert
+        sort_key = jnp.where(local, pair_expert - e_start, e_local)
+        order = jnp.argsort(sort_key, stable=True)
+        if capacity is not None and capacity < order.shape[0]:
+            order = order[:capacity]
+        sel_expert = sort_key[order]  # e_local == "dropped/non-local"
+        sel_valid = sel_expert < e_local
+        sel_token = pair_token[order]
+        sel_gate = jnp.where(sel_valid, pair_gate[order], 0.0)
+        group_sizes = jnp.bincount(
+            jnp.where(sel_valid, sel_expert, e_local), length=e_local + 1
+        )[:e_local].astype(jnp.int32)
+        counts = jnp.stack([group_sizes.sum(),
+                            (group_sizes > 0).sum(dtype=jnp.int32)])
 
-    xs = x_flat[sel_token]  # (M, D) gather
-    group_sizes = jnp.bincount(
-        jnp.where(sel_valid, sel_expert, e_local), length=e_local + 1
-    )[:e_local].astype(jnp.int32)
-    ys = _expert_ffn_sorted(p["experts"], xs, group_sizes)
-    ys = ys * sel_gate[:, None].astype(ys.dtype)
-    out = jnp.zeros((n, d), ys.dtype).at[sel_token].add(
-        jnp.where(sel_valid[:, None], ys, 0.0)
-    )
-    return out
+    with jax.named_scope("experts"):
+        xs = x_flat[sel_token]  # (M, D) gather
+        ys = _expert_ffn_sorted(p["experts"], xs, group_sizes)
+        ys = ys * sel_gate[:, None].astype(ys.dtype)
+        out = jnp.zeros((n, d), ys.dtype).at[sel_token].add(
+            jnp.where(sel_valid[:, None], ys, 0.0)
+        )
+    return out, counts
 
 
-def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, mesh=None) -> jax.Array:
-    """x: (B, T, D) -> (B, T, D)."""
+def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, mesh=None,
+              with_counts: bool = False):
+    """x: (B, T, D) -> (B, T, D); with ``with_counts`` also the counts of
+    :func:`_moe_local` (int32[2], local path only)."""
     b, t, d = x.shape
     x_flat = x.reshape(-1, d)
 
+    counts = None
     if cfg.impl == "local" or mesh is None:
-        routed = _moe_local(p, x_flat, cfg, 0, cfg.n_experts, None)
+        routed, counts = _moe_local(p, x_flat, cfg, cfg.expert_offset,
+                                    cfg.held, None)
     elif cfg.impl == "ep_psum":
         routed = _moe_ep_psum(p, x_flat, cfg, mesh)
     else:
@@ -146,12 +184,16 @@ def moe_apply(p: dict, x: jax.Array, cfg: MoEConfig, mesh=None) -> jax.Array:
 
     out = routed.reshape(b, t, d).astype(x.dtype)
     if "shared" in p:
-        out = out + swiglu(p["shared"], x)
-    return out
+        with jax.named_scope("shared_expert"):
+            out = out + swiglu(p["shared"], x)
+    return (out, counts) if with_counts else out
 
 
 def _moe_ep_psum(p: dict, x_flat: jax.Array, cfg: MoEConfig, mesh) -> jax.Array:
     """Expert-parallel execution: experts sharded over cfg.ep_axis."""
+    if cfg.experts_held:
+        raise ValueError("ep_psum shards all n_experts over the mesh; "
+                         "experts_held is for a chip that runs alone")
     ep = cfg.ep_axis
     n_shards = mesh.shape[ep]
     assert cfg.n_experts % n_shards == 0, (cfg.n_experts, n_shards)
@@ -165,7 +207,8 @@ def _moe_ep_psum(p: dict, x_flat: jax.Array, cfg: MoEConfig, mesh) -> jax.Array:
         cap = int(n_loc * cfg.top_k * cfg.capacity_factor / n_shards)
         cap = max(cap, cfg.top_k)
         p_loc = {"router": router, "experts": experts}
-        out = _moe_local(p_loc, xl, cfg, shard_id * e_local, e_local, cap)
+        out, _ = _moe_local(p_loc, xl, cfg, shard_id * e_local, e_local,
+                            cap)
         return jax.lax.psum(out, ep)
 
     return jax.shard_map(

@@ -305,14 +305,20 @@ class ServingEngine:
         #: contiguous layout (the paged step gathers and scatters blocks)
         self.kv_in_place = self._donate and not self._paged
 
+        #: MoE models' steps also return the MoE layers' counts, which the
+        #: engine reads after its token fetch (an ``engine.moe`` span)
+        self._moe = cfg.mlp == "moe"
+        self._moe_counts = None
+        kw = {"moe_counts": True} if self._moe else {}
+
         def step_fn(name: str):
             if self._paged:
                 def fn(p, t, c, nv, bt):
                     return decode_slots(p, t, c, nv, mesh=mesh,
-                                        block_tables=bt)
+                                        block_tables=bt, **kw)
             else:
                 def fn(p, t, c, nv):
-                    return decode_slots(p, t, c, nv, mesh=mesh)
+                    return decode_slots(p, t, c, nv, mesh=mesh, **kw)
 
             fn.__name__ = fn.__qualname__ = name
             return jax.jit(fn, compiler_options=STEP_COMPILER_OPTIONS,
@@ -475,6 +481,7 @@ class ServingEngine:
         else:
             pp_batch, q_finished, q_emitted, q_prompt = batch, [], 0, 0
         finished, emitted, prompt_toks = self._postprocess(pp_batch, logits)
+        self._record_moe()
         finished += q_finished
         emitted += q_emitted
         prompt_toks += q_prompt
@@ -622,7 +629,8 @@ class ServingEngine:
         args = (params, jnp.asarray(batch.tokens), self.pool.cache, n_valid)
         if self._paged:
             args += (jnp.asarray(tables),)
-        logits, cache = fn(*args)
+        logits, cache, *moe = fn(*args)
+        self._moe_counts = moe[0] if moe else None
         if self._donate:
             # the step consumed the pool's buffers.  Until the caller stores
             # the result, the pool holds it at the pre-step cursors: for
@@ -633,6 +641,18 @@ class ServingEngine:
             if self.kv_in_place:
                 self.metrics.kv_in_place_steps += 1
         return logits, cache
+
+    def _record_moe(self) -> None:
+        """After the token fetch: the last step's MoE counts as the
+        arguments ``pairs`` and ``groups_hit`` of a zero-length
+        ``engine.moe`` span, read only while a ring or a profiler trace
+        keeps them."""
+        if self._moe_counts is None or not (
+                self.tracer is not None
+                or jax.profiler.TraceAnnotation.is_enabled()):
+            return
+        pairs, groups = (int(v) for v in np.asarray(self._moe_counts))
+        telemetry.instant(self.tracer, "moe", pairs=pairs, groups_hit=groups)
 
     # -- speculative rounds (repro.serving.speculative) ----------------------
 

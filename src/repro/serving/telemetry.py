@@ -24,6 +24,10 @@ Engine phases (children of ``engine.step``, which is a
     the span in which the host waits for the device
   * ``emit``        — per-row advance, ``on_token`` callbacks, finishes
   * ``account``     — ``EngineMetrics.record_step`` and the window roll
+  * ``moe``         — zero-length, after the fetch of a step of a model
+    with MoE layers: ``pairs`` (the (token, held expert) pairs the step
+    computed, summed over the MoE layers, padding rows' included) and
+    ``groups_hit`` (the (layer, held expert) groups given a row)
   * ``probe``       — one approximation-error probe
     (:mod:`repro.quant.error_probe`); its result rides in args
   * ``shadow``      — one A/B shadow replay of a finished sampled request
@@ -107,7 +111,7 @@ PREFIX = "engine."
 #: engine phases (:class:`Span` kinds); ``engine.<kind>`` in the profiler
 PHASE_KINDS: tuple[str, ...] = (
     "step", "schedule", "admit", "dispatch", "fetch", "emit", "account",
-    "probe", "shadow", "quarantine")
+    "moe", "probe", "shadow", "quarantine")
 
 #: every ring event kind: the phases and the ring-only events
 SPAN_KINDS: tuple[str, ...] = PHASE_KINDS + (
